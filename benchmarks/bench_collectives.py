@@ -51,7 +51,6 @@ def _worker(fast: bool):
     import numpy as np
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
     from repro.core import (compressed_psum, compressed_psum_ef,
                             default_comm_config, dispatch_all_to_all)
     from repro.core.collectives import quantized_reduce_scatter_ef
@@ -81,7 +80,7 @@ def _worker(fast: bool):
         return {label: float(np.min(v)) for label, v in ts.items()}
 
     def ar_case(cfg, axes, n, outer_cfg=None):
-        @functools.partial(compat.shard_map, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=mesh,
                            in_specs=P(("pod", "data", "model")),
                            out_specs=P(("pod", "data", "model")),
                            check_vma=False)
@@ -97,7 +96,7 @@ def _worker(fast: bool):
         # train_step cross-pod sync path: two-step + residual
         # re-injection + both-stage error capture) — the rows track EF
         # overhead vs the plain compressed psum at 2/4 bit
-        @functools.partial(compat.shard_map, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=mesh,
                            in_specs=(P(("pod", "data", "model")),) * 2,
                            out_specs=P(("pod", "data", "model")),
                            check_vma=False)
@@ -114,7 +113,7 @@ def _worker(fast: bool):
         # pass in train_step): quantized+EF reduce-scatter over the
         # 4-wide model axis standing in for the fsdp axis — rows track
         # the qgrad wire cost and the rotated-vs-spike A/B at 2 bits
-        @functools.partial(compat.shard_map, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=mesh,
                            in_specs=(P(("pod", "data", "model")),) * 2,
                            out_specs=P(("pod", "data", "model")),
                            check_vma=False)
@@ -134,7 +133,7 @@ def _worker(fast: bool):
         d = 512
         m = n // (a2a_tp * d)
 
-        @functools.partial(compat.shard_map, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=mesh,
                            in_specs=P(("pod", "data", "model")),
                            out_specs=P(("pod", "data", "model")),
                            check_vma=False)
@@ -201,6 +200,9 @@ def _worker(fast: bool):
 
 def run(fast: bool = False):
     env = dict(os.environ)
+    # 8 host-platform devices on the CPU: the child must not reach for a
+    # TPU, which the parent (it has imported jax) may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = os.pathsep.join(

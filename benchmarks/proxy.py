@@ -17,7 +17,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs import get_smoke_config
 from repro.core.policy import BF16_POLICY, CommPolicy
 from repro.launch.mesh import make_test_mesh
@@ -76,7 +75,7 @@ def eval_loss(cfg, plan, mesh, store, ds, policy: CommPolicy,
         return lm_loss(hidden, unemb, batch["labels"], cfg, plan, aux,
                        aux_weight=0.0)
     bs = {"tokens": P(), "labels": P()}
-    sm = jax.jit(compat.shard_map(f, mesh=mesh, in_specs=(STORE_SPEC, bs),
+    sm = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(STORE_SPEC, bs),
                                out_specs=P(), check_vma=False))
     tot = 0.0
     for i in range(1000, 1000 + n_batches):      # held-out batches

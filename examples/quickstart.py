@@ -14,7 +14,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import (CommConfig, codec, compressed_psum,
                         default_comm_config)
 from repro.core.spike import spike_qdq
@@ -50,7 +49,7 @@ ref = np.sum(np.asarray(xs), axis=0)
 for scheme, bits in (("two_step", 8), ("hierarchical", 4), ("hier_pp", 2)):
     cfg = default_comm_config(bits, scheme=scheme)
 
-    @partial(compat.shard_map, mesh=mesh, in_specs=P(("pod", "data", "model")),
+    @partial(jax.shard_map, mesh=mesh, in_specs=P(("pod", "data", "model")),
              out_specs=P(("pod", "data", "model")), check_vma=False)
     def ar(v):
         return compressed_psum(v[0], ("model", "pod"), cfg)[None]

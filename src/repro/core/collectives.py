@@ -43,7 +43,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
 from repro.core import codec
 from repro.core.comm_config import CommConfig
 
@@ -70,7 +69,7 @@ def padded_len(n: int, mult: int) -> int:
 # --------------------------------------------------------------------------
 
 def _gsize(axis, groups):
-    return len(groups[0]) if groups is not None else compat.axis_size(axis)
+    return len(groups[0]) if groups is not None else jax.lax.axis_size(axis)
 
 
 def quantized_all_reduce(x: jnp.ndarray, axis: str,
@@ -136,7 +135,7 @@ def quantized_reduce_scatter(x: jnp.ndarray, axis: str,
     its own chunk), so any ``n % tp == 0`` length compresses instead of
     only ``group``-aligned ones. No-op for aligned sizes.
     """
-    tp = compat.axis_size(axis)
+    tp = jax.lax.axis_size(axis)
     n = x.shape[-1]
     lead = x.shape[:-1]
     b = len(lead)
@@ -252,12 +251,12 @@ def hierarchical_all_reduce(x: jnp.ndarray, inner_axis: str, outer_axis: str,
     batched through one schedule (how ``hier_pp`` rides this function).
     """
     outer_cfg = outer_cfg or cfg
-    inner = compat.axis_size(inner_axis)
+    inner = jax.lax.axis_size(inner_axis)
     n = x.shape[-1]
     b = x.ndim - 1
     assert n % inner == 0 and (n // inner) % cfg.group == 0
     chunk = quantized_reduce_scatter(x, inner_axis, cfg)     # (..., n/inner)
-    outer = compat.axis_size(outer_axis)
+    outer = jax.lax.axis_size(outer_axis)
     if outer > 1:
         if (n // inner) % (outer * outer_cfg.group) == 0:
             chunk = quantized_all_reduce(chunk, outer_axis, outer_cfg)
@@ -289,7 +288,7 @@ def pipelined_hierarchical_all_reduce(x: jnp.ndarray, inner_axis: str,
     with the intra-pod stages of the next wave (paper: up to 20%).
     """
     chunks = max(1, cfg.pipeline_chunks)
-    inner = compat.axis_size(inner_axis)
+    inner = jax.lax.axis_size(inner_axis)
     n = x.shape[-1]
     mult = inner * cfg.group * chunks
     assert n % mult == 0, (n, mult)
@@ -392,7 +391,7 @@ def compressed_psum(x: jnp.ndarray, axes: tuple, cfg: CommConfig,
         for s in x.shape:
             n *= s
         return out[:n].reshape(x.shape).astype(x.dtype)
-    sizes = [compat.axis_size(a) for a in axes]
+    sizes = [jax.lax.axis_size(a) for a in axes]
     chunks = cfg.pipeline_chunks if cfg.scheme == "hier_pp" else 1
     mult = sizes[0] * _group_mult(cfg, outer_cfg) * chunks
     for s in sizes[1:]:
@@ -462,7 +461,7 @@ def _ef_two_step(xe_flat: jnp.ndarray, axis: str, cfg: CommConfig):
     wire dropped — the strongest EF the schedule admits. Leading batch
     dims pipeline through one schedule (the hier_pp microchunk path).
     """
-    tp = compat.axis_size(axis)
+    tp = jax.lax.axis_size(axis)
     lead = xe_flat.shape[:-1]
     b = len(lead)
     m = xe_flat.shape[-1]
@@ -517,7 +516,7 @@ def compressed_psum_ef(x: jnp.ndarray, residual: jnp.ndarray, axes: tuple,
     xe = x.astype(jnp.float32) + residual.astype(jnp.float32)
     if len(axes) == 1 and groups is None and \
             cfg.scheme in ("two_step", "hierarchical", "hier_pp"):
-        tp = compat.axis_size(axes[0])
+        tp = jax.lax.axis_size(axes[0])
         chunks = cfg.pipeline_chunks if cfg.scheme == "hier_pp" else 1
         xf = _pad_to(xe.reshape(-1), tp * cfg.group * chunks)
         if chunks > 1:          # hier_pp: batched microchunk pipeline
@@ -527,7 +526,7 @@ def compressed_psum_ef(x: jnp.ndarray, residual: jnp.ndarray, axes: tuple,
                 res.reshape(-1)[:n].reshape(shape).astype(residual.dtype))
     out = compressed_psum(xe, axes, cfg, groups)
     sizes = [len(groups[0])] if groups is not None \
-        else [compat.axis_size(a) for a in axes]
+        else [jax.lax.axis_size(a) for a in axes]
     chunks = cfg.pipeline_chunks if cfg.scheme == "hier_pp" else 1
     mult = cfg.group * chunks
     for s in sizes:
@@ -571,7 +570,7 @@ def quantized_reduce_scatter_ef(x: jnp.ndarray, residual: jnp.ndarray,
         out = lax.psum_scatter(x, axis, scatter_dimension=x.ndim - 1,
                                tiled=True)
         return out, residual
-    tp = compat.axis_size(axis)
+    tp = jax.lax.axis_size(axis)
     m = x.shape[-1] // tp
     xe = x.astype(jnp.float32) + residual.astype(jnp.float32)
     out = quantized_reduce_scatter(xe, axis, cfg)
@@ -628,7 +627,7 @@ def grad_all_reduce(grads, axes: Sequence[str], cfg: CommConfig,
     """
     denom = 1
     for a in axes:
-        denom *= compat.axis_size(a)
+        denom *= jax.lax.axis_size(a)
 
     def one(g):
         out = compressed_psum(g, tuple(axes), cfg, None, None, outer_cfg)

@@ -5,27 +5,24 @@ reshaped to ``(..., n_groups, group)``) asymmetric RTN with BF16 scales
 and zeros. ``bits`` may be anything in 2..8 — the packing of irregular
 widths is handled separately by :mod:`repro.core.bitsplit`.
 
-The group min/max is ONE variadic ``lax.reduce`` pass (not two separate
-reductions — measurably ~2x on the reduction, and the encode hot path
-runs this on every wire tile). NaN propagation matches ``jnp.min``/
-``jnp.max`` (``minimum``/``maximum`` comparators).
+The ``*_groups`` forms take the grouped tensor and the axis that runs
+within a group: the wire tile bodies (:mod:`repro.core.tilecodec`) put
+the group on a leading axis of a transposed tile, where the group
+reduction is a sublane reduction on TPU. The min and max are two plain
+reductions (a variadic ``lax.reduce`` has no Pallas TPU lowering); both
+propagate NaN like ``jnp.min``/``jnp.max``.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 _EPS = 1e-12
 
 
-def group_min_max(xg: jnp.ndarray):
-    """(..., group) -> (min, max) over the last axis, one fused pass."""
-    return lax.reduce(
-        (xg, xg),
-        (jnp.float32(jnp.inf), jnp.float32(-jnp.inf)),
-        lambda a, b: (jnp.minimum(a[0], b[0]), jnp.maximum(a[1], b[1])),
-        (xg.ndim - 1,))
+def group_min_max(xg: jnp.ndarray, axis: int = -1):
+    """(min, max) over the in-group ``axis``."""
+    return jnp.min(xg, axis=axis), jnp.max(xg, axis=axis)
 
 
 def group_reshape(x: jnp.ndarray, group: int) -> jnp.ndarray:
@@ -39,6 +36,34 @@ def group_unreshape(xg: jnp.ndarray) -> jnp.ndarray:
     return xg.reshape(*xg.shape[:-2], xg.shape[-2] * xg.shape[-1])
 
 
+def quantize_groups(xg: jnp.ndarray, bits: int, meta_dtype=jnp.bfloat16,
+                    axis: int = -1):
+    """RTN on a grouped f32 tensor whose ``axis`` runs within a group.
+
+    Returns (codes int32 in [0, 2^bits-1], same shape as ``xg``;
+    scale, zero in ``meta_dtype`` with ``axis`` reduced away).
+    """
+    qmax = float(2 ** bits - 1)
+    mn, mx = group_min_max(xg, axis)
+    scale = (mx - mn) / qmax
+    # Store meta at wire precision, then quantize *with the stored values*
+    # so encode/decode are self-consistent.
+    scale_w = jnp.maximum(scale, _EPS).astype(meta_dtype)
+    zero_w = mn.astype(meta_dtype)
+    s = jnp.expand_dims(scale_w.astype(jnp.float32), axis)
+    z = jnp.expand_dims(zero_w.astype(jnp.float32), axis)
+    codes = jnp.clip(jnp.round((xg - z) / s), 0.0, qmax).astype(jnp.int32)
+    return codes, scale_w, zero_w
+
+
+def dequantize_groups(codes: jnp.ndarray, scale: jnp.ndarray,
+                      zero: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
+    """Inverse of :func:`quantize_groups`; grouped f32 result."""
+    s = jnp.expand_dims(scale.astype(jnp.float32), axis)
+    z = jnp.expand_dims(zero.astype(jnp.float32), axis)
+    return codes.astype(jnp.float32) * s + z
+
+
 def quantize(x: jnp.ndarray, bits: int, group: int,
              meta_dtype=jnp.bfloat16):
     """Asymmetric RTN. Returns (codes uint8, scale, zero), grouped shapes.
@@ -46,26 +71,15 @@ def quantize(x: jnp.ndarray, bits: int, group: int,
     codes: (..., n_groups, group) uint8 in [0, 2^bits-1]
     scale/zero: (..., n_groups) meta_dtype
     """
-    xg = group_reshape(x.astype(jnp.float32), group)
-    qmax = float(2 ** bits - 1)
-    mn, mx = group_min_max(xg)
-    scale = (mx - mn) / qmax
-    # Store meta at wire precision, then quantize *with the stored values*
-    # so encode/decode are self-consistent.
-    scale_w = jnp.maximum(scale, _EPS).astype(meta_dtype)
-    zero_w = mn.astype(meta_dtype)
-    s = scale_w.astype(jnp.float32)[..., None]
-    z = zero_w.astype(jnp.float32)[..., None]
-    codes = jnp.clip(jnp.round((xg - z) / s), 0.0, qmax).astype(jnp.uint8)
-    return codes, scale_w, zero_w
+    codes, scale_w, zero_w = quantize_groups(
+        group_reshape(x.astype(jnp.float32), group), bits, meta_dtype)
+    return codes.astype(jnp.uint8), scale_w, zero_w
 
 
 def dequantize(codes: jnp.ndarray, scale: jnp.ndarray, zero: jnp.ndarray,
                out_dtype=jnp.float32) -> jnp.ndarray:
     """Inverse of :func:`quantize`; returns the flat (..., n) tensor."""
-    s = scale.astype(jnp.float32)[..., None]
-    z = zero.astype(jnp.float32)[..., None]
-    xg = codes.astype(jnp.float32) * s + z
+    xg = dequantize_groups(codes, scale, zero)
     return group_unreshape(xg).astype(out_dtype)
 
 

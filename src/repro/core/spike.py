@@ -11,36 +11,41 @@ Implementation: the old argmin/argmax + ``take_along_axis`` +
 ``nanmin``/``nanmax`` pipeline cost five variadic/gather reductions per
 group — by far the hottest part of the low-bit encode path (XLA lowers
 variadic arg-reductions and gathers to scalar loops on several
-backends). It is now plain vectorized min/max lane reductions plus
+backends). It is now plain vectorized min/max reductions plus
 first-match index selection:
 
-* the spike *values* are ONE fused (NaN-propagating) min+max reduction —
+* the spike *values* are a NaN-propagating min and max —
   no gather: the min/max of a group IS an element of it, bit-exactly;
-* the spike *indices* are ONE more fused pass: first position matching
-  the min, and the two first positions matching the max (an associative
-  top-2 min network — only min/max lane ops, so the variadic reduce
-  stays vectorized), so a group whose min and max collide on the same
-  slot (constant groups, duplicated extremes, multi-NaN) still reserves
-  two distinct slots with first-occurrence tie-breaking — exactly the
-  old argmin/argmax-over-masked behaviour;
-* the shrunk range is one fused min/max pass with the spike slots (and
-  NaNs, matching ``nanmin``/``nanmax``) masked out; a group whose
-  remaining values are all NaN yields NaN scale/zero, ditto.
+* the spike *indices* are min reductions of masked positions: the
+  first position matching the min, and the first two matching the max,
+  so a group whose min and max collide on the same slot (constant
+  groups, duplicated extremes, multi-NaN) still reserves two distinct
+  slots with first-occurrence tie-breaking — exactly the old
+  argmin/argmax-over-masked behaviour;
+* the shrunk range is a min and a max with the spike slots (and NaNs,
+  matching ``nanmin``/``nanmax``) masked out; a group whose remaining
+  values are all NaN yields NaN scale/zero, ditto.
+
+Every reduction is a plain ``min``/``max`` over the in-group axis (no
+variadic ``lax.reduce``, which has no Pallas TPU lowering), so the
+``*_groups`` forms also run inside compiled TPU kernels on the
+transposed tiles of :mod:`repro.core.tilecodec`.
 
 NaN semantics (diverged grads): a NaN group propagates NaN min/max, the
 first NaN claims the min slot and the second NaN (if any) the max slot,
 as before. The one deliberate change: a group with exactly ONE NaN used
 to reserve its finite max as the second spike; it now forfeits the max
 slot (both recorded spikes are the NaN) — the group is already poisoned,
-and keeping the fast fused election is worth more than reserving a
+and keeping the fast election is worth more than reserving a
 finite extreme next to a NaN.
 
-All of this is pure jnp (compare/select lane ops), used verbatim by
+All of this is pure jnp (compare/select ops), used verbatim by
 every backend — the jnp reference, the Pallas kernels and the RDMA
 collectives — so spike bytes cannot drift between them.
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax.numpy as jnp
@@ -59,81 +64,61 @@ class SpikeQuant(NamedTuple):
     spike_idx: jnp.ndarray   # (..., n_groups, 2) int8 in-group positions
 
 
-def _min_max(xg: jnp.ndarray):
-    """Fused NaN-propagating (min, max) over the last axis, one pass."""
-    return lax.reduce(
-        (xg, xg), (jnp.float32(jnp.inf), jnp.float32(-jnp.inf)),
-        lambda a, b: (jnp.minimum(a[0], b[0]), jnp.maximum(a[1], b[1])),
-        (xg.ndim - 1,))
+def _spike_positions(eq_min, eq_max, pos, group: int, axis: int):
+    """First ``eq_min`` position, and first and second ``eq_max``
+    positions, over the in-group ``axis`` (``group`` where none)."""
+    big = jnp.int32(group)
+    imin = jnp.min(jnp.where(eq_min, pos, big), axis=axis)
+    imax1 = jnp.min(jnp.where(eq_max, pos, big), axis=axis)
+    second = eq_max & (pos != jnp.expand_dims(imax1, axis))
+    imax2 = jnp.min(jnp.where(second, pos, big), axis=axis)
+    return imin, imax1, imax2
 
 
-def _spike_positions(eq_min, eq_max, pos, group: int):
-    """One fused pass: (first eq_min pos, first and second eq_max pos).
+def spike_quantize_groups(xg: jnp.ndarray, bits: int,
+                          meta_dtype=jnp.bfloat16, axis: int = -1):
+    """Spike-reserving RTN on a grouped f32 tensor whose ``axis`` runs
+    within a group.
 
-    The top-2 selection for eq_max is an associative min network (only
-    min/max lane ops, so the reduce stays vectorized). A single element
-    summarizes as ``(pos, group)`` — the third operand is the constant
-    ``group`` so singletons don't count twice in the top-2 merge.
-
-    Everything runs on uint8 lanes (in-group positions are < 128, and
-    the ``group`` sentinel still fits) — 4x the SIMD width and a quarter
-    of the memory traffic of int32 positions on this, the hottest
-    reduction of the low-bit encode path.
+    Returns ``(codes int32, scale, zero, (vmin, vmax), (imin, imax))``:
+    codes shaped like ``xg``, the rest with ``axis`` reduced away;
+    spike values are f32 (exact elements of the group), indices int32.
     """
-    big = jnp.uint8(group)
-    pmin = jnp.where(eq_min, pos, big)
-    pmax = jnp.where(eq_max, pos, big)
-
-    def comp(a, b):
-        i_a, t1a, t2a = a
-        i_b, t1b, t2b = b
-        t1 = jnp.minimum(t1a, t1b)
-        t2 = jnp.minimum(jnp.maximum(t1a, t1b), jnp.minimum(t2a, t2b))
-        return (jnp.minimum(i_a, i_b), t1, t2)
-
-    return lax.reduce((pmin, pmax, jnp.full_like(pmax, big)),
-                      (big, big, big), comp, (pos.ndim - 1,))
-
-
-def spike_quantize(x: jnp.ndarray, bits: int, group: int,
-                   meta_dtype=jnp.bfloat16) -> SpikeQuant:
+    axis %= xg.ndim
+    group = xg.shape[axis]
     assert group <= 128, "in-group spike indices are int8 on the wire"
-    xg = group_reshape(x.astype(jnp.float32), group)
     qmax = float(2 ** bits - 1)
-    pos = lax.broadcasted_iota(jnp.uint8, xg.shape, xg.ndim - 1)
+    pos = lax.broadcasted_iota(jnp.int32, xg.shape, axis)
     nan = jnp.isnan(xg)
+    ex = functools.partial(jnp.expand_dims, axis=axis)
 
-    # spike values: one fused NaN-propagating min+max pass (the extreme
-    # of a group is an element of it, so the value bits are exact)
-    vmin, vmax = _min_max(xg)
-    has_nan = jnp.isnan(vmin)
+    # spike values: NaN-propagating min and max (the extreme of a group
+    # is an element of it, so the value bits are exact)
+    vmin, vmax = jnp.min(xg, axis=axis), jnp.max(xg, axis=axis)
+    has_nan = ex(jnp.isnan(vmin))
 
     # spike indices: first min match, first + second max match (second
     # resolves min/max landing on the same slot: constant groups,
     # duplicated extremes, >= 2 NaNs)
-    eq_min = jnp.where(has_nan[..., None], nan, xg == vmin[..., None])
-    eq_max = jnp.where(has_nan[..., None], nan, xg == vmax[..., None])
-    imin, imax1, imax2 = _spike_positions(eq_min, eq_max, pos, group)
+    # (boolean and/or, not a select of masks: Mosaic has no i1 select)
+    eq_min = (has_nan & nan) | (~has_nan & (xg == ex(vmin)))
+    eq_max = (has_nan & nan) | (~has_nan & (xg == ex(vmax)))
+    imin, imax1, imax2 = _spike_positions(eq_min, eq_max, pos, group, axis)
     imax = jnp.where(imax1 == imin, imax2, imax1)
     # single-NaN groups forfeit the max slot (imax2 is the out-of-range
     # sentinel); keep the wire index valid by pointing it at the min
     # slot — both spikes are the NaN, and the decode scatter writes the
     # same NaN there twice
-    imax = jnp.where(imax == jnp.uint8(group), imin, imax)
-    min_mask = pos == imin[..., None]
-    max_mask = pos == imax[..., None]
-    spike_mask = min_mask | max_mask
+    imax = jnp.where(imax == group, imin, imax)
+    min_mask = pos == ex(imin)
+    max_mask = pos == ex(imax)
 
     # Shrunk range over the remaining group-2 values (NaNs ignored, as
     # nanmin/nanmax did; all-NaN remainder -> NaN scale/zero, ditto).
     # Each side only needs its own spike slot masked: leaving the max in
     # cannot move a min (and vice versa), so the masks stay one compare.
-    mn, mx = lax.reduce(
-        (jnp.where(min_mask | nan, jnp.inf, xg),
-         jnp.where(max_mask | nan, -jnp.inf, xg)),
-        (jnp.float32(jnp.inf), jnp.float32(-jnp.inf)),
-        lambda a, b: (jnp.minimum(a[0], b[0]), jnp.maximum(a[1], b[1])),
-        (xg.ndim - 1,))
+    mn = jnp.min(jnp.where(min_mask | nan, jnp.inf, xg), axis=axis)
+    mx = jnp.max(jnp.where(max_mask | nan, -jnp.inf, xg), axis=axis)
     # both extremes untouched by data <=> every remaining value was NaN
     all_dropped = (mn == jnp.inf) & (mx == -jnp.inf)
     mn = jnp.where(all_dropped, jnp.float32(jnp.nan), mn)
@@ -142,37 +127,53 @@ def spike_quantize(x: jnp.ndarray, bits: int, group: int,
     scale = (mx - mn) / qmax
     scale_w = jnp.maximum(scale, _EPS).astype(meta_dtype)
     zero_w = mn.astype(meta_dtype)
-    s = scale_w.astype(jnp.float32)[..., None]
-    z = zero_w.astype(jnp.float32)[..., None]
+    s = scale_w.astype(jnp.float32)
+    z = zero_w.astype(jnp.float32)
     # Spike slots are set to the new minimum before quantization (paper:
     # "set them to zeros" of the shrunk range); their codes are dummies
     # overwritten on dequant. Quantizing xg everywhere and patching the
     # spike slots with the (per-group) code of `mn` afterwards is the
-    # same arithmetic per element, but moves the select from float lanes
-    # to uint8 code lanes.
-    codes = jnp.clip(jnp.round((xg - z) / s), 0.0, qmax).astype(jnp.uint8)
-    code_mn = jnp.clip(jnp.round((mn - z[..., 0]) / s[..., 0]),
-                       0.0, qmax).astype(jnp.uint8)
-    codes = jnp.where(spike_mask, code_mn[..., None], codes)
+    # same arithmetic per element.
+    codes = jnp.clip(jnp.round((xg - ex(z)) / ex(s)),
+                     0.0, qmax).astype(jnp.int32)
+    code_mn = jnp.clip(jnp.round((mn - z) / s), 0.0, qmax).astype(jnp.int32)
+    codes = jnp.where(min_mask | max_mask, ex(code_mn), codes)
+    return codes, scale_w, zero_w, (vmin, vmax), (imin, imax)
 
-    spike_vals = jnp.stack([vmin, vmax], axis=-1).astype(meta_dtype)
-    spike_idx = jnp.stack([imin, imax], axis=-1).astype(jnp.int8)
-    return SpikeQuant(codes, scale_w, zero_w, spike_vals, spike_idx)
+
+def spike_dequantize_groups(codes, scale, zero, spike_vals, spike_idx,
+                            axis: int = -1) -> jnp.ndarray:
+    """Inverse of :func:`spike_quantize_groups`: grouped f32 result.
+
+    ``spike_vals`` / ``spike_idx`` are the (min, max) pairs, each with
+    ``axis`` reduced away; the exact spikes are written back by one-hot
+    selects (the group is small).
+    """
+    ex = functools.partial(jnp.expand_dims, axis=axis)
+    xg = (codes.astype(jnp.float32) * ex(scale.astype(jnp.float32))
+          + ex(zero.astype(jnp.float32)))
+    pos = lax.broadcasted_iota(jnp.int32, xg.shape, axis % xg.ndim)
+    for val, idx in zip(spike_vals, spike_idx):
+        hit = pos == ex(idx.astype(jnp.int32))
+        xg = jnp.where(hit, ex(val.astype(jnp.float32)), xg)
+    return xg
+
+
+def spike_quantize(x: jnp.ndarray, bits: int, group: int,
+                   meta_dtype=jnp.bfloat16) -> SpikeQuant:
+    codes, scale_w, zero_w, vals, idx = spike_quantize_groups(
+        group_reshape(x.astype(jnp.float32), group), bits, meta_dtype)
+    spike_vals = jnp.stack(vals, axis=-1).astype(meta_dtype)
+    spike_idx = jnp.stack(idx, axis=-1).astype(jnp.int8)
+    return SpikeQuant(codes.astype(jnp.uint8), scale_w, zero_w,
+                      spike_vals, spike_idx)
 
 
 def spike_dequantize(q: SpikeQuant, out_dtype=jnp.float32) -> jnp.ndarray:
     codes, scale, zero, spike_vals, spike_idx = q
-    s = scale.astype(jnp.float32)[..., None]
-    z = zero.astype(jnp.float32)[..., None]
-    xg = codes.astype(jnp.float32) * s + z
-    # Scatter the exact spikes back (one-hot writes; group is small).
-    group = xg.shape[-1]
-    pos = jnp.arange(group, dtype=jnp.int32)
-    idx = spike_idx.astype(jnp.int32)
-    vals = spike_vals.astype(jnp.float32)
-    for k in range(2):
-        hit = pos == idx[..., k][..., None]
-        xg = jnp.where(hit, vals[..., k][..., None], xg)
+    xg = spike_dequantize_groups(
+        codes, scale, zero, (spike_vals[..., 0], spike_vals[..., 1]),
+        (spike_idx[..., 0], spike_idx[..., 1]))
     return group_unreshape(xg).astype(out_dtype)
 
 

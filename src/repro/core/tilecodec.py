@@ -1,27 +1,28 @@
 """Shared wire-format tile bodies: the one codec implementation.
 
 ``encode_tile`` / ``decode_tile`` are the complete per-tile codec bodies
-as pure ``(R, n) <-> (R, wire_bytes(n))`` array functions, and
-``encode_tile_into`` is the ref-writing variant for Pallas kernels. They
-are THE wire codec: the jnp reference backend (:mod:`repro.core.codec`),
-the fused Pallas wire kernels (:mod:`repro.kernels.wire`), the fused RDMA
+as pure ``(R, n) <-> (R, wire_bytes(n))`` array functions. They are THE
+wire codec: the jnp reference backend (:mod:`repro.core.codec`), the
+fused Pallas wire kernels (:mod:`repro.kernels.wire`), the fused RDMA
 collectives (:mod:`repro.kernels.rdma_allreduce`,
 :mod:`repro.kernels.rdma_all2all`) and their CPU emulation
 (:mod:`repro.kernels.emulate`) all run these exact functions, so the
 backends cannot drift byte-wise (tests/test_wire_golden.py,
 tests/test_backend_equality.py).
 
-Performance shape (the hot path of the repo):
-
-* sections are written at the static offsets of
-  :meth:`repro.core.comm_config.CommConfig.wire_layout` — straight into
-  the output ref's slices inside kernels (``encode_tile_into``), via
-  in-place buffer updates in the pure form; no ``jnp.concatenate``
-  reassembly of the payload;
-* the bit-plane pack/unpack is the word-parallel uint32 shift/or tree of
-  :mod:`repro.core.wordpack` (no 8x byte-expand lanes);
-* the Eq.-1 scale/zero codec is the transcendental-free exponent
-  arithmetic of :mod:`repro.core.scale_codec`.
+Shape of the computation: the tile is transposed first, so the values
+of a row run along the leading axis and the rows along the last one.
+Groups are then a leading-axis reshape ``(n/group, group, R)``, group
+min/max are reductions over that middle axis, and bit-plane packing and
+the byte interleave of 2-byte metadata are leading-axis reshapes too.
+On TPU these are sublane operations, which the Pallas compiler (Mosaic)
+lowers; the same steps along the lane axis (a ``group``-wide lane split,
+a strided lane slice, a bitcast that changes the element width) it
+refuses. All bytes are carried as int32 until the one final
+``uint8`` cast of the assembled ``(R, total)`` wire tile. The
+word-level pack/unpack is :mod:`repro.core.wordpack`, the Eq.-1
+scale/zero codec the transcendental-free exponent arithmetic of
+:mod:`repro.core.scale_codec`.
 
 Everything here is pure jnp — valid under jit/vmap/shard_map and inside
 Pallas kernel bodies (interpret or compiled).
@@ -34,8 +35,8 @@ import jax.numpy as jnp
 from repro.core import rotation as rot
 from repro.core import scale_codec, wordpack
 from repro.core.comm_config import WireLayout, _wire_layout
-from repro.core.quant import dequantize, quantize
-from repro.core.spike import SpikeQuant, spike_dequantize, spike_quantize
+from repro.core.quant import dequantize_groups, quantize_groups
+from repro.core.spike import spike_dequantize_groups, spike_quantize_groups
 
 
 def tile_layout(n: int, *, bits: int, group: int, spike: bool,
@@ -56,16 +57,27 @@ def tile_kwargs(cfg, n: int) -> dict:
                 theta=cfg.theta, meta_dtype=jnp.dtype(cfg.meta_dtype))
 
 
-def _meta_to_bytes(m: jnp.ndarray) -> jnp.ndarray:
-    """(R, k) 2-byte meta dtype -> (R, 2k) uint8, little-endian pairs."""
-    b = jax.lax.bitcast_convert_type(m, jnp.uint8)        # (R, k, 2)
-    return b.reshape(*m.shape[:-1], -1)
+def _meta_bytes(m: jnp.ndarray) -> jnp.ndarray:
+    """(k, R) 2-byte meta values -> (2k, R) int32 bytes, little-endian
+    pairs along the leading axis."""
+    if m.dtype == jnp.bfloat16:     # bf16 bits are the top half of f32's
+        bits = jax.lax.bitcast_convert_type(m.astype(jnp.float32),
+                                            jnp.int32) >> 16
+    else:
+        bits = jax.lax.bitcast_convert_type(m, jnp.uint16).astype(jnp.int32)
+    pair = jnp.stack([bits & 0xFF, (bits >> 8) & 0xFF], axis=1)
+    return pair.reshape(2 * m.shape[0], *m.shape[1:])
 
 
-def _bytes_to_meta(b: jnp.ndarray, dtype, k: int) -> jnp.ndarray:
-    """(R, 2k) uint8 -> (R, k) 2-byte meta dtype."""
+def _bytes_meta(b: jnp.ndarray, dtype) -> jnp.ndarray:
+    """(2k, R) int32 bytes -> (k, R) f32 holding the 2-byte meta values
+    exactly (inverse of :func:`_meta_bytes`)."""
+    pair = b.reshape(b.shape[0] // 2, 2, *b.shape[1:])
+    bits = pair[:, 0] | (pair[:, 1] << 8)
+    if jnp.dtype(dtype) == jnp.bfloat16:
+        return jax.lax.bitcast_convert_type(bits << 16, jnp.float32)
     return jax.lax.bitcast_convert_type(
-        b.reshape(*b.shape[:-1], k, 2), jnp.dtype(dtype))
+        bits.astype(jnp.uint16), jnp.dtype(dtype)).astype(jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -75,13 +87,14 @@ def _bytes_to_meta(b: jnp.ndarray, dtype, k: int) -> jnp.ndarray:
 def encode_sections(x: jnp.ndarray, *, bits: int, group: int, n: int,
                     spike: bool, scale_int: bool, theta: int, meta_dtype,
                     rotation: bool = False):
-    """(R, n) float tile -> [(Section, uint8 bytes), ...] in wire order.
+    """(R, n) float tile -> [(Section, (nbytes, R) int32 bytes), ...] in
+    wire order — each section transposed, bytes along the leading axis.
 
-    The single place the wire format is produced; both ``encode_tile``
-    variants just place these sections. With ``rotation`` each group is
-    Hadamard-rotated (f32) before quantization — the wire then carries
-    rotated coordinates under the identical section layout (spike
-    sections are absent by construction: rotation replaces reserving).
+    The single place the wire format is produced. With ``rotation`` each
+    group is Hadamard-rotated (f32) before quantization — the wire then
+    carries rotated coordinates under the identical section layout
+    (spike sections are absent by construction: rotation replaces
+    reserving).
     """
     assert x.shape[-1] == n, (x.shape, n)
     rows = x.shape[0]
@@ -92,62 +105,53 @@ def encode_sections(x: jnp.ndarray, *, bits: int, group: int, n: int,
     if rotation:
         assert not spike
         x = rot.rotate(x, group)
+    xg = x.astype(jnp.float32).T.reshape(g, group, rows)
     if spike:
-        q = spike_quantize(x, bits, group, meta_dtype)
-        codes, scale_w, zero_w = q.codes, q.scale, q.zero
+        codes, scale_w, zero_w, vals, idx = spike_quantize_groups(
+            xg, bits, meta_dtype, axis=1)
     else:
-        codes, scale_w, zero_w = quantize(x, bits, group, meta_dtype)
-    codes = codes.reshape(rows, n)
+        codes, scale_w, zero_w = quantize_groups(xg, bits, meta_dtype,
+                                                 axis=1)
+    codes = codes.reshape(n, rows)
 
     out = []
     for (unit, span), (u2, plane) in zip(
-            layout.planes, wordpack.pack_codes(codes, bits)):
-        assert unit == u2 and plane.shape[-1] == span.nbytes
+            layout.planes, wordpack.pack_codes(codes, bits, axis=0)):
+        assert unit == u2 and plane.shape[0] == span.nbytes
         out.append((span, plane))                         # bit splitting
 
     if scale_int:                                         # paper Eq. 1
-        out.append((layout.scale, jax.lax.bitcast_convert_type(
-            scale_codec.encode_scale(scale_w, theta), jnp.uint8)))
-        out.append((layout.zero,
-                    scale_codec.encode_signed(zero_w, theta)))
+        out.append((layout.scale, scale_codec.encode_scale(
+            scale_w, theta).astype(jnp.int32) & 0xFF))
+        out.append((layout.zero, scale_codec.encode_signed(
+            zero_w, theta).astype(jnp.int32)))
     else:
-        out.append((layout.scale, _meta_to_bytes(scale_w)))
-        out.append((layout.zero, _meta_to_bytes(zero_w)))
+        out.append((layout.scale, _meta_bytes(scale_w)))
+        out.append((layout.zero, _meta_bytes(zero_w)))
 
     if spike:                                             # paper Fig. 5c
-        sv = q.spike_vals.reshape(rows, 2 * g)            # exact bf16
-        out.append((layout.spike_vals, _meta_to_bytes(sv)))
-        si = q.spike_idx.reshape(rows, 2 * g)
-        if scale_int:                                     # int8 indices
-            out.append((layout.spike_idx,
-                        jax.lax.bitcast_convert_type(si, jnp.uint8)))
-        else:                                             # bf16 baseline
-            out.append((layout.spike_idx,
-                        _meta_to_bytes(si.astype(meta_dtype))))
+        # per group [min, max]: values exact at meta width, indices
+        # int8 with scale_int (all < 128), meta-width otherwise
+        sv = jnp.stack(vals, axis=1).reshape(2 * g, rows)
+        out.append((layout.spike_vals, _meta_bytes(sv.astype(meta_dtype))))
+        si = jnp.stack(idx, axis=1).reshape(2 * g, rows)
+        out.append((layout.spike_idx, si if scale_int
+                    else _meta_bytes(si.astype(meta_dtype))))
     return out
-
-
-def encode_tile_into(x: jnp.ndarray, wire_ref, **kw) -> None:
-    """Encode an (R, n) tile, writing each wire section straight into its
-    ``wire_layout`` slice of ``wire_ref`` (a Pallas ref or any object
-    supporting 2-D slice assignment). No concatenate, no second pass."""
-    for span, sec in encode_sections(x, **kw):
-        wire_ref[:, span.offset:span.end] = sec
 
 
 def encode_tile(x: jnp.ndarray, *, bits: int, group: int, n: int,
                 spike: bool, scale_int: bool, theta: int,
                 meta_dtype, rotation: bool = False) -> jnp.ndarray:
     """(R, n) float tile -> (R, wire_bytes(n)) uint8 wire tile (pure)."""
-    layout = tile_layout(n, bits=bits, group=group, spike=spike,
-                         scale_int=scale_int)
-    buf = jnp.zeros((x.shape[0], layout.total), jnp.uint8)
-    for span, sec in encode_sections(
-            x, bits=bits, group=group, n=n, spike=spike,
-            scale_int=scale_int, theta=theta, meta_dtype=meta_dtype,
-            rotation=rotation):
-        buf = buf.at[:, span.offset:span.end].set(sec)
-    return buf
+    secs = encode_sections(
+        x, bits=bits, group=group, n=n, spike=spike, scale_int=scale_int,
+        theta=theta, meta_dtype=meta_dtype, rotation=rotation)
+    # the layout's sections are contiguous and in wire order
+    assert [span.offset for span, _ in secs] == \
+        [0] + [span.end for span, _ in secs[:-1]]
+    buf = jnp.concatenate([sec for _, sec in secs], axis=0)
+    return buf.T.astype(jnp.uint8)
 
 
 # ---------------------------------------------------------------------------
@@ -163,37 +167,41 @@ def decode_tile(wire: jnp.ndarray, *, bits: int, group: int, n: int,
     layout = tile_layout(n, bits=bits, group=group, spike=spike,
                          scale_int=scale_int)
     assert wire.shape[-1] == layout.total, (wire.shape, layout.total)
+    w = wire.astype(jnp.int32).T                          # (total, R)
+
+    def sec(span):
+        return w[span.offset:span.end]
 
     def read_plane(i, unit, nbytes):
         span = layout.planes[i][1]
         assert span.nbytes == nbytes
-        return wire[:, span.offset:span.end]
+        return sec(span)
 
-    codes = wordpack.unpack_codes(read_plane, bits, n)
+    codes = wordpack.unpack_codes(read_plane, bits, n, axis=0)
+    codes = codes.reshape(g, group, rows)
 
-    sb = wire[:, layout.scale.offset:layout.scale.end]
-    zb = wire[:, layout.zero.offset:layout.zero.end]
     if scale_int:
+        sb = sec(layout.scale)
         scale = scale_codec.decode_scale(
-            jax.lax.bitcast_convert_type(sb, jnp.int8), theta)
-        zero = scale_codec.decode_signed(zb, theta)
+            jnp.where(sb >= 128, sb - 256, sb), theta)    # int8 code
+        zero = scale_codec.decode_signed(sec(layout.zero), theta)
     else:
-        scale = _bytes_to_meta(sb, meta_dtype, g)
-        zero = _bytes_to_meta(zb, meta_dtype, g)
+        scale = _bytes_meta(sec(layout.scale), meta_dtype)
+        zero = _bytes_meta(sec(layout.zero), meta_dtype)
 
-    codes = codes.reshape(rows, g, group)
     if spike:
-        svb = wire[:, layout.spike_vals.offset:layout.spike_vals.end]
-        sv = _bytes_to_meta(svb, meta_dtype, 2 * g)
-        sib = wire[:, layout.spike_idx.offset:layout.spike_idx.end]
-        if scale_int:
-            si = jax.lax.bitcast_convert_type(sib, jnp.int8)
-        else:
-            si = _bytes_to_meta(sib, meta_dtype, 2 * g).astype(jnp.int8)
-        q = SpikeQuant(codes, scale, zero,
-                       sv.reshape(rows, g, 2), si.reshape(rows, g, 2))
-        return spike_dequantize(q, out_dtype)
+        sv = _bytes_meta(sec(layout.spike_vals), meta_dtype)
+        si = sec(layout.spike_idx)
+        if not scale_int:
+            si = _bytes_meta(si, meta_dtype).astype(jnp.int32)
+        sv = sv.reshape(g, 2, rows)
+        si = si.reshape(g, 2, rows)
+        xg = spike_dequantize_groups(codes, scale, zero,
+                                     (sv[:, 0], sv[:, 1]),
+                                     (si[:, 0], si[:, 1]), axis=1)
+    else:
+        xg = dequantize_groups(codes, scale, zero, axis=1)
+    out = xg.reshape(n, rows).T
     if rotation:
-        deq = dequantize(codes, scale, zero, jnp.float32)
-        return rot.unrotate(deq, group).astype(out_dtype)
-    return dequantize(codes, scale, zero, out_dtype)
+        out = rot.unrotate(out, group)
+    return out.astype(out_dtype)

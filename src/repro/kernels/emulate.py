@@ -3,9 +3,9 @@
 The real things (:mod:`repro.kernels.rdma_allreduce`,
 :mod:`repro.kernels.rdma_all2all`) run Pallas kernels on TPU: quantize +
 bit-split pack + RDMA push (``make_async_remote_copy``) + dequant
-(+ local reduce for the AllReduce), all in VMEM. Remote DMA cannot
-execute off-TPU (jax 0.4.37 has no cross-device interpret mode), so this
-module runs the *same* kernel bodies —
+(+ local reduce for the AllReduce), all in VMEM. Remote DMA does not
+execute in plain interpret mode off-TPU, so this module runs the *same*
+kernel bodies —
 :func:`repro.kernels.wire.encode_tile` /
 :func:`repro.kernels.wire.decode_tile`, the exact functions the RDMA
 kernels call — as interpret-mode ``pallas_call``s on every shard, and
@@ -37,7 +37,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-from repro import compat
 from repro.core.comm_config import CommConfig
 from repro.kernels.wire import _cfg_kw, decode_tile, encode_tile
 
@@ -172,7 +171,7 @@ def fused_all_reduce_emulated(x: jnp.ndarray, axis: str, cfg: CommConfig,
     if groups is not None:
         tp = len(groups[0])
     else:
-        tp = compat.axis_size(axis)
+        tp = jax.lax.axis_size(axis)
     n = x.shape[-1]
     assert n % tp == 0 and (n // tp) % cfg.group == 0, (n, tp, cfg.group)
     chunk = n // tp
@@ -210,7 +209,7 @@ def fused_all_to_all_emulated(x: jnp.ndarray, axis: str, cfg: CommConfig,
     if groups is not None:
         tp = len(groups[0])
     else:
-        tp = compat.axis_size(axis)
+        tp = jax.lax.axis_size(axis)
     assert x.shape[0] == tp, (x.shape, tp)
     d = x.shape[-1]
     assert d % cfg.group == 0, (d, cfg.group)

@@ -18,18 +18,18 @@ hop, so unlike the two-phase AllReduce there is only one kernel):
 
 A per-peer block is the ``m`` payload rows destined for that peer (for
 MoE dispatch: ``e_loc * capacity`` token rows of width ``d_model``),
-staged as one contiguous ``m * wire_bytes(d)`` RDMA chunk so each peer
-gets exactly one remote copy regardless of how many tokens it carries.
+staged as one ``(m, wire_bytes(d))`` slab (lanes padded to 128) so each
+peer gets exactly one remote copy regardless of how many tokens it
+carries.
 
 Addressing, barriers and per-peer semaphore slotting are shared with
 :mod:`repro.kernels.rdma_allreduce` (``_peer_coords`` / ``_ring_barrier``
 / ``_push_rows``), so both RDMA kernels have one choreography to
-validate on hardware. Off TPU this cannot execute (remote DMA has no CPU
-lowering on the pinned jax); :func:`repro.kernels.emulate.
-fused_all_to_all_emulated` runs the same tile bodies with the push
-emulated by ``lax.all_to_all``, and :func:`repro.kernels.ops.
-fused_all_to_all` picks between them. Compiled-TPU validation is tracked
-in ROADMAP "Open items".
+validate on hardware. Off TPU this does not execute (remote DMA has no
+CPU lowering); :func:`repro.kernels.emulate.fused_all_to_all_emulated`
+runs the same tile bodies with the push emulated by ``lax.all_to_all``,
+and :func:`repro.kernels.ops.fused_all_to_all` picks between them.
+tests/test_tpu_compile.py compiles it for a described v5e 2x2.
 """
 from __future__ import annotations
 
@@ -43,33 +43,32 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 from repro.core.comm_config import CommConfig
 from repro.kernels.protocol import A2A_COLLECTIVE_ID, all2all_protocol
 from repro.kernels.rdma_allreduce import (_cfg_kw, _push_rows,
-                                          _ring_barrier)
+                                          _ring_barrier, _scratch, _stage,
+                                          slab_width)
 from repro.kernels.wire import decode_tile, encode_tile
 
 __all__ = ["A2A_COLLECTIVE_ID", "fused_all_to_all_rdma"]
 
 
 def _a2a_kernel(x_ref, out_ref, send_buf, recv_buf, send_sem, recv_sem,
-                *, axis: str, mesh_axes: Sequence[str], tp: int, m: int,
+                *, axis: str, mesh_axes: Sequence[str], tp: int, wb: int,
                 kw: dict, out_dtype, proto):
     my = lax.axis_index(axis)
-    wire = encode_tile(x_ref[...], **kw)                  # (tp*m, wb)
-    wb = wire.shape[1]
-    send_buf[...] = wire.reshape(tp, m * wb)
+    # one (m, wb) wire slab per destination on the buffers' leading axis
+    for p in range(tp):
+        _stage(send_buf, p, encode_tile(x_ref[p], **kw))
     _ring_barrier(my, tp, axis, mesh_axes, proto.barrier)
-    # push block p of my wire to peer p; it lands in recv_buf[my] there,
-    # so recv_buf[j] here is peer j's block my — lax.all_to_all order
+    # push slab p of my wire to peer p; it lands in recv_buf[my] there,
+    # so recv_buf[j] here is peer j's slab my — lax.all_to_all order
     _push_rows(send_buf, recv_buf, send_sem, recv_sem, my, tp,
                axis, mesh_axes, proto)
-    # own block never crossed the link: splice send row my in at row my
-    iota = lax.broadcasted_iota(jnp.int32, (tp, m * wb), 0)
-    mixed = jnp.where(iota == my, send_buf[...], recv_buf[...])
-    out_ref[...] = decode_tile(mixed.reshape(tp * m, wb),
-                               out_dtype=out_dtype, **kw)
+    # own slab never crossed the link: decode send_buf[my] for slab my
+    for p in range(tp):
+        wire = jnp.where(my == p, send_buf[p, :, :wb], recv_buf[p, :, :wb])
+        out_ref[p] = decode_tile(wire, out_dtype=out_dtype, **kw)
 
 
 def fused_all_to_all_rdma(x: jnp.ndarray, axis: str, cfg: CommConfig,
@@ -85,7 +84,7 @@ def fused_all_to_all_rdma(x: jnp.ndarray, axis: str, cfg: CommConfig,
     mesh has axes other than ``axis``. Wire bytes are identical to
     ``codec.encode`` (shared tile bodies; see tests/test_wire_golden.py).
     """
-    tp = compat.axis_size(axis)
+    tp = jax.lax.axis_size(axis)
     assert tp > 1, "RDMA path needs peers; use the emulation for tp == 1"
     assert x.shape[0] == tp, (x.shape, tp)
     d = x.shape[-1]
@@ -101,17 +100,13 @@ def fused_all_to_all_rdma(x: jnp.ndarray, axis: str, cfg: CommConfig,
     proto = all2all_protocol(tp)
     out = pl.pallas_call(
         functools.partial(_a2a_kernel, axis=axis, mesh_axes=mesh_axes,
-                          tp=tp, m=m, kw=kw, out_dtype=x.dtype,
+                          tp=tp, wb=wb, kw=kw, out_dtype=x.dtype,
                           proto=proto),
-        out_shape=jax.ShapeDtypeStruct((tp * m, d), x.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((proto.buffer("send").rows, m * wb), jnp.uint8),
-            pltpu.VMEM((proto.buffer("recv").rows, m * wb), jnp.uint8),
-            pltpu.SemaphoreType.DMA((proto.sem_slots,)),
-            pltpu.SemaphoreType.DMA((proto.sem_slots,)),
-        ],
-        compiler_params=pltpu.TPUCompilerParams(
+        out_shape=jax.ShapeDtypeStruct((tp, m, d), x.dtype),
+        name="rdma_all2all",
+        scratch_shapes=_scratch(proto, m, slab_width(wb)),
+        compiler_params=pltpu.CompilerParams(
             collective_id=proto.collective_id),
-    )(x.reshape(tp * m, d))
+    )(x.reshape(tp, m, d))
 
     return out.reshape(x.shape)

@@ -31,15 +31,17 @@ shapes come from the protocol fields. The same declaration is what
 :mod:`repro.analysis.choreography` statically verifies (deadlock
 freedom, slot matching, write-before-wait races) per mesh shape.
 
-Off TPU this cannot execute (remote DMA has no CPU lowering on the
-pinned jax); :mod:`repro.kernels.emulate` runs the same tile bodies with
-the push emulated by XLA collectives, and :func:`repro.kernels.ops.
-fused_all_reduce` picks between them. Compiled-TPU validation of this
-module is tracked in ROADMAP "Open items".
+Off TPU this does not execute (remote DMA has no CPU lowering);
+:mod:`repro.kernels.emulate` runs the same tile bodies with the push
+emulated by XLA collectives, and :func:`repro.kernels.ops.
+fused_all_reduce` picks between them. tests/test_tpu_compile.py compiles
+both phases for a described v5e 2x2; ``chip_smoke.py --chips 4`` runs
+them.
 """
 from __future__ import annotations
 
 import functools
+import math
 from typing import Sequence
 
 import jax
@@ -48,13 +50,12 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 from repro.core.comm_config import CommConfig
 from repro.kernels.protocol import (KernelProtocol, RingBarrier,
                                     allreduce_gather_protocol,
                                     allreduce_scatter_protocol,
                                     resolve_row)
-from repro.kernels.wire import _cfg_kw, decode_tile, encode_tile_into
+from repro.kernels.wire import _cfg_kw, decode_tile, encode_tile
 
 
 def _peer_coords(dst, axis: str, mesh_axes: Sequence[str]):
@@ -106,50 +107,90 @@ def _push_rows(src_buf, dst_buf, send_sem, recv_sem, my, tp: int,
 
 
 # ---------------------------------------------------------------------------
+# staging slabs
+# ---------------------------------------------------------------------------
+
+#: A VMEM DMA moves whole (rows, 128-lane) uint8 tiles, so each peer's
+#: wire is staged as one slab on the buffer's leading axis, padded with
+#: zero bytes to a lane multiple; uint8 packs 4 rows per 32-bit word, so
+#: a single row cannot be addressed on its own.
+_LANES = 128
+_SLAB_ROWS = 8
+
+
+def slab_width(wb: int) -> int:
+    """Staged bytes per wire row: ``wb`` rounded up to whole lanes."""
+    return -(-wb // _LANES) * _LANES
+
+
+def slab_rows(chunk: int, group: int) -> int:
+    """Rows a peer's ``chunk`` values are encoded as (each row a whole
+    number of groups, so the quantized values are the same as one row's;
+    only the byte arrangement on the link differs)."""
+    return math.gcd(_SLAB_ROWS, chunk // group)
+
+
+def _stage(buf, p: int, wire) -> None:
+    """Write wire tile ``wire`` into slab ``p`` of ``buf``, zero tail."""
+    rows, wb = wire.shape
+    buf[p, :, :wb] = wire
+    if buf.shape[-1] > wb:
+        buf[p, :, wb:] = jnp.zeros((rows, buf.shape[-1] - wb), jnp.uint8)
+
+
+# ---------------------------------------------------------------------------
 # phase kernels
 # ---------------------------------------------------------------------------
 
 def _scatter_reduce_kernel(x_ref, partial_ref, send_buf, recv_buf,
                            send_sem, recv_sem, *, axis: str,
-                           mesh_axes: Sequence[str], tp: int, kw: dict,
-                           proto: KernelProtocol):
+                           mesh_axes: Sequence[str], tp: int, wb: int,
+                           kw: dict, proto: KernelProtocol):
     my = lax.axis_index(axis)
-    # encode the tp per-peer rows section-by-section straight into the
-    # send staging buffer at wire_layout offsets (no concatenate pass)
-    encode_tile_into(x_ref[...], send_buf, **kw)          # (tp, wb)
-    wire = send_buf[...]
+    for p in range(tp):                    # encode the tp per-peer slabs
+        _stage(send_buf, p, encode_tile(x_ref[p], **kw))
     _ring_barrier(my, tp, axis, mesh_axes, proto.barrier)
-    # push row p of my wire to peer p; it lands in recv_buf[my] over there
+    # push slab p of my wire to peer p; it lands in recv_buf[my] over there
     _push_rows(send_buf, recv_buf, send_sem, recv_sem, my, tp,
                axis, mesh_axes, proto)
-    # own chunk never crossed the link: splice wire[my] in at row my
-    iota = lax.broadcasted_iota(jnp.int32, wire.shape, 0)
-    mixed = jnp.where(iota == my, wire, recv_buf[...])
-    parts = decode_tile(mixed, out_dtype=jnp.float32, **kw)
-    partial_ref[...] = jnp.sum(parts, axis=0, keepdims=True)
+    # own chunk never crossed the link: decode send_buf[my] for slab my
+    acc = None
+    for p in range(tp):
+        wire = jnp.where(my == p, send_buf[p, :, :wb], recv_buf[p, :, :wb])
+        part = decode_tile(wire, out_dtype=jnp.float32, **kw)
+        acc = part if acc is None else acc + part
+    partial_ref[...] = acc
 
 
 def _gather_kernel(partial_ref, out_ref, send_buf, gather_buf,
                    send_sem, recv_sem, *, axis: str,
-                   mesh_axes: Sequence[str], tp: int, kw: dict,
+                   mesh_axes: Sequence[str], tp: int, wb: int, kw: dict,
                    proto: KernelProtocol):
     my = lax.axis_index(axis)
-    encode_tile_into(partial_ref[...], send_buf, **kw)    # (1, wb)
-    wire = send_buf[...]
+    _stage(send_buf, 0, encode_tile(partial_ref[...], **kw))
     _ring_barrier(my, tp, axis, mesh_axes, proto.barrier)
-    # push my (single) partial-sum row into every peer's slot my
+    # push my (single) partial-sum slab into every peer's slot my
     _push_rows(send_buf, gather_buf, send_sem, recv_sem, my, tp,
                axis, mesh_axes, proto)
-    iota = lax.broadcasted_iota(jnp.int32, (tp, wire.shape[1]), 0)
-    gathered = jnp.where(iota == my,
-                         jnp.broadcast_to(wire, (tp, wire.shape[1])),
-                         gather_buf[...])
-    out_ref[...] = decode_tile(gathered, out_dtype=jnp.float32, **kw)
+    for p in range(tp):
+        wire = jnp.where(my == p, send_buf[0, :, :wb],
+                         gather_buf[p, :, :wb])
+        out_ref[p] = decode_tile(wire, out_dtype=jnp.float32, **kw)
 
 
 # ---------------------------------------------------------------------------
 # public entry point (call inside shard_map, TPU only)
 # ---------------------------------------------------------------------------
+
+def _scratch(proto: KernelProtocol, rows: int, width: int) -> list:
+    """Send/receive slabs and DMA semaphores, shaped by the protocol."""
+    return [
+        pltpu.VMEM((proto.buffer("send").rows, rows, width), jnp.uint8),
+        pltpu.VMEM((proto.buffer("recv").rows, rows, width), jnp.uint8),
+        pltpu.SemaphoreType.DMA((proto.sem_slots,)),
+        pltpu.SemaphoreType.DMA((proto.sem_slots,)),
+    ]
+
 
 def fused_all_reduce_rdma(x: jnp.ndarray, axis: str, cfg: CommConfig,
                           mesh_axes: Sequence[str] | None = None
@@ -158,47 +199,42 @@ def fused_all_reduce_rdma(x: jnp.ndarray, axis: str, cfg: CommConfig,
 
     Must be called inside shard_map on TPU with ``tp > 1``; pass
     ``mesh_axes`` (all mesh axis names, in mesh order) when the mesh has
-    axes other than ``axis``. Wire bytes are identical to
-    ``codec.encode`` (shared tile bodies; see tests/test_wire_golden.py).
+    axes other than ``axis``. Each wire row is ``codec.encode`` of
+    ``chunk / slab_rows`` values (shared tile bodies; see
+    tests/test_wire_golden.py).
     """
-    tp = compat.axis_size(axis)
+    tp = jax.lax.axis_size(axis)
     assert tp > 1, "RDMA path needs peers; use the emulation for tp == 1"
     n = x.shape[-1]
     assert n % tp == 0 and (n // tp) % cfg.group == 0, (n, tp, cfg.group)
     chunk = n // tp
-    wb = cfg.wire_layout(chunk).total     # send/recv buffer addressing
+    rows = slab_rows(chunk, cfg.group)
+    cols = chunk // rows
+    wb = cfg.wire_layout(cols).total      # send/recv buffer addressing
     mesh_axes = tuple(mesh_axes) if mesh_axes else (axis,)
     assert axis in mesh_axes, (axis, mesh_axes)
-    kw = _cfg_kw(cfg, chunk)
 
-    comm = dict(axis=axis, mesh_axes=mesh_axes, tp=tp, kw=kw)
+    comm = dict(axis=axis, mesh_axes=mesh_axes, tp=tp, wb=wb,
+                kw=_cfg_kw(cfg, cols))
     # scratch shapes and collective ids come from the declared protocol
     # — the same object repro.analysis.choreography statically verifies
     sp = allreduce_scatter_protocol(tp)
     partial = pl.pallas_call(
         functools.partial(_scatter_reduce_kernel, proto=sp, **comm),
-        out_shape=jax.ShapeDtypeStruct((1, chunk), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((sp.buffer("send").rows, wb), jnp.uint8),
-            pltpu.VMEM((sp.buffer("recv").rows, wb), jnp.uint8),
-            pltpu.SemaphoreType.DMA((sp.sem_slots,)),
-            pltpu.SemaphoreType.DMA((sp.sem_slots,)),
-        ],
-        compiler_params=pltpu.TPUCompilerParams(
+        name="rdma_allreduce_scatter",
+        out_shape=jax.ShapeDtypeStruct((rows, cols), jnp.float32),
+        scratch_shapes=_scratch(sp, rows, slab_width(wb)),
+        compiler_params=pltpu.CompilerParams(
             collective_id=sp.collective_id),
-    )(x.reshape(tp, chunk).astype(jnp.float32))
+    )(x.reshape(tp, rows, cols).astype(jnp.float32))
 
     gp = allreduce_gather_protocol(tp)
     full = pl.pallas_call(
         functools.partial(_gather_kernel, proto=gp, **comm),
-        out_shape=jax.ShapeDtypeStruct((tp, chunk), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((gp.buffer("send").rows, wb), jnp.uint8),
-            pltpu.VMEM((gp.buffer("recv").rows, wb), jnp.uint8),
-            pltpu.SemaphoreType.DMA((gp.sem_slots,)),
-            pltpu.SemaphoreType.DMA((gp.sem_slots,)),
-        ],
-        compiler_params=pltpu.TPUCompilerParams(
+        name="rdma_allreduce_gather",
+        out_shape=jax.ShapeDtypeStruct((tp, rows, cols), jnp.float32),
+        scratch_shapes=_scratch(gp, rows, slab_width(wb)),
+        compiler_params=pltpu.CompilerParams(
             collective_id=gp.collective_id),
     )(partial)
 

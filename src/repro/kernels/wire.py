@@ -2,29 +2,29 @@
 
 The earlier kernels (:mod:`repro.kernels.quant_pack`,
 :mod:`repro.kernels.spike_reserve`) stop at raw payload/scale/zero
-tensors; the codec then still had to assemble the metadata sections in
-plain jnp. These kernels go all the way: one grid step reads a
+tensors. These kernels go all the way: one grid step reads a
 ``(block_rows, n)`` float tile from VMEM and writes the full
-``(block_rows, wire_bytes(n))`` uint8 wire buffer —
+``(block_rows, wire_bytes(n))`` uint8 wire tile —
 
     [bit-split packed codes | scales | zeros | spike vals | spike idx]
 
-— every section written straight into its
-:meth:`repro.core.comm_config.CommConfig.wire_layout` slice of the
-output ref (no ``jnp.concatenate`` staging), including the integer-log
-scale/zero encoding (paper Eq. 1, transcendental-free exponent
-arithmetic) and the spike-reserving metadata (paper Fig. 5c). The tensor
-is read from HBM exactly once and only wire bytes leave the kernel.
+— with every section at its
+:meth:`repro.core.comm_config.CommConfig.wire_layout` offset, including
+the integer-log scale/zero encoding (paper Eq. 1, transcendental-free
+exponent arithmetic) and the spike-reserving metadata (paper Fig. 5c).
+The tensor is read from HBM exactly once and only wire bytes leave the
+kernel.
 
 The kernel bodies are :mod:`repro.core.tilecodec` — the same functions
 the pure-jnp reference backend runs — so the byte layout is identical to
 :mod:`repro.core.codec` by construction (enforced anyway by
-tests/test_backend_equality.py and the golden vectors).
+tests/test_backend_equality.py and the golden vectors);
+tests/test_tpu_compile.py compiles them for a described v5e.
 
 ``block_rows`` is picked by the dispatchers in :mod:`repro.kernels.ops`
 from the tile size (whole-array single grid step off-TPU; VMEM-budgeted
-multiple of 8 sublanes on TPU) instead of the old fixed 8-row blocks
-that forced a re-pad and an 8x-deeper grid on every call.
+multiple of 8 sublanes on TPU, with rows longer than one step takes cut
+into sub-rows there).
 """
 from __future__ import annotations
 
@@ -38,7 +38,7 @@ from repro.core.comm_config import CommConfig
 # Shared tile bodies (re-exported: the RDMA kernels and the emulation
 # import them from here so all fused call sites read as one module).
 from repro.core.tilecodec import (decode_tile, encode_tile,  # noqa: F401
-                                  encode_tile_into, tile_kwargs)
+                                  tile_kwargs)
 
 _cfg_kw = tile_kwargs
 
@@ -48,7 +48,7 @@ _cfg_kw = tile_kwargs
 # ---------------------------------------------------------------------------
 
 def _encode_kernel(x_ref, wire_ref, *, kw):
-    encode_tile_into(x_ref[...], wire_ref, **kw)
+    wire_ref[...] = encode_tile(x_ref[...], **kw)
 
 
 @functools.partial(jax.jit,
@@ -76,6 +76,7 @@ def encode_wire(x: jnp.ndarray, *, bits: int, group: int, spike: bool,
     grid = (rows // block,)
     return pl.pallas_call(
         functools.partial(_encode_kernel, kw=kw),
+        name="wire_encode",
         grid=grid,
         in_specs=[pl.BlockSpec((block, n), lambda r: (r, 0))],
         out_specs=[pl.BlockSpec((block, wb), lambda r: (r, 0))],
@@ -119,6 +120,7 @@ def decode_wire(buf: jnp.ndarray, *, bits: int, group: int, n: int,
         grid=grid,
         in_specs=[pl.BlockSpec((block, wb), lambda r: (r, 0))],
         out_specs=[pl.BlockSpec((block, n), lambda r: (r, 0))],
+        name="wire_decode",
         out_shape=[jax.ShapeDtypeStruct((rows, n), jnp.dtype(out_dtype))],
         interpret=interpret,
     )(buf)[0]

@@ -95,15 +95,6 @@ def collective_bytes(hlo_text: str) -> Dict[str, int]:
     return out
 
 
-def _cost_dict(compiled) -> Dict:
-    """compiled.cost_analysis() normalized across jax versions: 0.4.x
-    returns a one-dict-per-device list, newer versions a flat dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        return cost[0] if cost else {}
-    return cost
-
-
 def input_specs(cfg: ModelConfig, shape_name: str, mesh,
                 cache_len: Optional[int] = None):
     """ShapeDtypeStruct stand-ins for every model input (no allocation)."""
@@ -282,7 +273,7 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
         n_dev *= v
 
     mem = compiled.memory_analysis()
-    cost = _cost_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     try:
         hlo = compiled.as_text()
     except Exception:
@@ -354,7 +345,7 @@ def _measure(cfg, shape_name, lp, pol, mesh, micro) -> Dict:
                                   window_override=lp.window_override)
             lowered = fn.lower(store, cshapes, batch)
         compiled = lowered.compile()
-    cost = _cost_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     try:
         hlo = compiled.as_text()
     except Exception:

@@ -13,13 +13,8 @@ import jax
 
 
 def _make(shape, axes):
-    # jax.sharding.AxisType only exists from jax 0.5; on older versions
-    # (the pinned 0.4.37) every axis is implicitly Auto already.
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

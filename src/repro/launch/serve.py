@@ -19,6 +19,7 @@ from repro.core.comm_config import SCHEMES
 from repro.core.policy import (BF16_POLICY, aggressive_policy,
                                describe_policy, load_policy_file,
                                paper_policy, with_backend, with_scheme)
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_test_mesh
 from repro.models.model import param_groups
 from repro.parallel.plan import make_plan
@@ -31,7 +32,7 @@ POLICIES = {"paper": paper_policy, "bf16": lambda: BF16_POLICY,
             "aggressive": aggressive_policy}
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list(ARCH_IDS), required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -55,9 +56,14 @@ def main(argv=None):
                     help="run the full commcheck pre-launch pass (site "
                          "lint, choreography, layout/VMEM) and abort "
                          "before compiling anything if a rule fires")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
-    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
+
+def run(cfg, args: argparse.Namespace) -> dict:
+    """Serve one batch of ``cfg`` as ``args`` (from :func:`parse_args`)
+    say: prefill, then the decode loop over the prompt and ``args.gen``
+    new tokens. Returns the tokens and the host-clock timings."""
+    enable_compile_cache()
     data_n, model_n = (int(x) for x in args.mesh.split(","))
     mesh = make_test_mesh(data=data_n, model=model_n)
     plan = make_plan(cfg, tp=model_n, fsdp=data_n)
@@ -153,6 +159,15 @@ def main(argv=None):
     print(f"[serve] generated tokens (first row): {gen[0][:16]}")
     assert np.all((gen >= 0) & (gen < cfg.vocab))
     print("[serve] OK")
+    return {"first": np.asarray(first), "generated": gen,
+            "prefill_first_call_s": ttft, "decode_first_step_s": t_compile,
+            "decode_steady_s": t_steady, "decode_steps": steps}
+
+
+def main(argv=None) -> dict:
+    args = parse_args(argv)
+    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
+    return run(cfg, args)
 
 
 if __name__ == "__main__":

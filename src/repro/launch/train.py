@@ -26,6 +26,7 @@ from repro.core.policy import (BF16_POLICY, aggressive_policy,
                                load_policy_file, paper_policy,
                                with_backend, with_framed_bridge,
                                with_scheme)
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_test_mesh
 from repro.models.model import param_groups
 from repro.parallel.plan import make_plan
@@ -40,7 +41,7 @@ POLICIES = {"paper": paper_policy, "bf16": lambda: BF16_POLICY,
             "aggressive": aggressive_policy, "depth": depth_policy}
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list(ARCH_IDS), required=True)
     ap.add_argument("--smoke", action="store_true",
@@ -83,9 +84,13 @@ def main(argv=None):
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--resume", default=None)
     ap.add_argument("--log-every", type=int, default=10)
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
-    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
+
+def run(cfg, args: argparse.Namespace):
+    """Train ``cfg`` as ``args`` (from :func:`parse_args`) say.
+    Returns ``(store, opt_state, history)``."""
+    enable_compile_cache()
     mesh_dims = [int(x) for x in args.mesh.split(",")]
     data_n, model_n = mesh_dims[0], mesh_dims[1]
     pod_n = mesh_dims[2] if len(mesh_dims) > 2 else 0
@@ -185,6 +190,12 @@ def main(argv=None):
     print(json.dumps({"first_loss": history[0]["loss"],
                       "last_loss": history[-1]["loss"]}))
     return store, opt, history
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
+    return run(cfg, args)
 
 
 if __name__ == "__main__":

@@ -31,10 +31,12 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
+import zlib
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
@@ -89,41 +91,32 @@ class ParamSpec:
         return flat_store_len(self.numel_loc(plan), plan.fsdp)
 
 
-def _init_values(spec: ParamSpec, key, rank: int, plan: ShardingPlan,
-                 dtype) -> jnp.ndarray:
-    """Per-rank local values. TP-sliced params fold the rank into the key
-    (slices are independent); replicated params share the key so every
-    rank holds identical values."""
+def _fill_values(out: np.ndarray, spec: ParamSpec, seed: Tuple[int, ...],
+                 rank: int, plan: ShardingPlan) -> None:
+    """Write one rank's local f32 values into the flat ``out`` (zeros;
+    its tail beyond the values stays zero padding). TP-sliced params add
+    the rank to the seed (slices are independent); replicated params
+    share the seed so every rank holds identical values."""
     shape = spec.local_shape(plan)
+    v = out[:math.prod(shape)]
     if spec.init == "zeros":
-        return jnp.zeros(shape, dtype)
+        return
     if spec.init == "ones":
-        return jnp.ones(shape, dtype)
-    k = key if (spec.tp_dim is None and spec.moe_fold is None) \
-        else jax.random.fold_in(key, rank)
+        v[:] = 1.0
+        return
+    if spec.tp_dim is not None or spec.moe_fold is not None:
+        seed = seed + (rank,)
+    np.random.default_rng(seed).random(dtype=np.float32, out=v)
     if spec.init == "lru_lambda":
         # RG-LRU: a = exp(-c*softplus(L)*r); init recurrence ~U(0.9, 0.999)
-        u = jax.random.uniform(k, shape, jnp.float32, 0.9, 0.999)
-        lam = jnp.log(jnp.exp(-jnp.log(u) / 8.0) - 1.0)  # inv softplus
-        return lam.astype(dtype)
-    # fan_in normal
+        u = v * np.float32(0.099) + np.float32(0.9)
+        v[:] = np.log(np.exp(-np.log(u) / 8.0) - 1.0)  # inv softplus
+        return
+    # fan-in scaled: uniform with std 1/sqrt(fan_in), drawn in place
     fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
-    std = 1.0 / math.sqrt(max(fan_in, 1))
-    return (jax.random.normal(k, shape, jnp.float32) * std).astype(dtype)
-
-
-def init_store_rank(specs: Dict[str, ParamSpec], key, rank: int,
-                    plan: ShardingPlan, n_stack: int, stack_idx: int,
-                    dtype) -> Dict[str, jnp.ndarray]:
-    """One rank's flat payloads for one stack index (used by the builder)."""
-    out = {}
-    for name, spec in sorted(specs.items()):
-        k = jax.random.fold_in(jax.random.fold_in(key, stack_idx),
-                               hash(name) % (2 ** 31))
-        v = _init_values(spec, k, rank, plan, dtype).reshape(-1)
-        pad = spec.flat_len(plan) - v.shape[0]
-        out[name] = jnp.pad(v, (0, pad))
-    return out
+    half = np.float32(math.sqrt(3.0 / max(fan_in, 1)))
+    v *= 2 * half
+    v -= half
 
 
 # --------------------------------------------------------------------------
@@ -213,21 +206,55 @@ def store_shapes(groups: Dict[str, Tuple[int, Dict[str, ParamSpec]]],
 
 def build_store(groups, plan: ShardingPlan, key, dtype,
                 mesh=None) -> Dict[str, Dict[str, jnp.ndarray]]:
-    """Materialize storage arrays (host build; fine at test scale)."""
+    """Materialize storage arrays ``(n_stack, tp, flat)`` from ``key``.
+
+    Values are drawn on the host (numpy), so building compiles nothing;
+    the TPU compiler takes minutes to relayout a flat payload into the
+    ``(n_stack, 1, flat)`` storage form. With a ``mesh`` each device
+    receives only its own TP rank's ``data`` slice: the store is never
+    whole on one device (a full-width model's store outgrows any one
+    chip long before it outgrows the mesh).
+    """
+    base = tuple(int(w) for w in np.asarray(
+        jax.random.key_data(key) if jax.dtypes.issubdtype(
+            key.dtype, jax.dtypes.prng_key) else key).ravel())
     out = {}
     for gi, (gname, (n_stack, specs)) in enumerate(sorted(groups.items())):
-        gkey = jax.random.fold_in(key, gi)
-        acc = {name: [] for name in specs}
-        for si in range(n_stack):
-            per_rank = []
-            for r in range(plan.tp):
-                per_rank.append(init_store_rank(specs, gkey, r, plan,
-                                                n_stack, si, dtype))
+        def rank_values(r, n_stack=n_stack, specs=specs, seed=base + (gi,)):
+            vals = {}
+            for name, spec in sorted(specs.items()):
+                # crc32, not hash(): str hashes change between processes
+                tag = zlib.crc32(name.encode())
+                v = np.zeros((n_stack, 1, spec.flat_len(plan)), np.float32)
+                for si in range(n_stack):
+                    _fill_values(v[si, 0], spec, seed + (si, tag), r, plan)
+                vals[name] = v if np.dtype(dtype) == np.float32 \
+                    else v.astype(dtype)
+            return vals
+
+        if mesh is None:
+            ranks = [rank_values(r) for r in range(plan.tp)]
+            out[gname] = {name: jnp.asarray(np.concatenate(
+                [v[name] for v in ranks], axis=1)) for name in specs}
+            continue
+        sharding = jax.sharding.NamedSharding(mesh, STORE_SPEC)
+        shapes = {name: (n_stack, plan.tp, spec.flat_len(plan))
+                  for name, spec in specs.items()}
+        shards = {name: [] for name in specs}
+        index_map = sharding.addressable_devices_indices_map(
+            next(iter(shapes.values())))
+        by_rank = {}
+        for dev, idx in index_map.items():
+            rank = idx[1].start or 0
+            if rank not in by_rank:
+                by_rank = {rank: rank_values(rank)}   # one rank on host
             for name in specs:
-                acc[name].append(jnp.stack([pr[name] for pr in per_rank]))
-        arrs = {name: jnp.stack(acc[name]) for name in specs}
-        if mesh is not None:
-            sharding = jax.sharding.NamedSharding(mesh, STORE_SPEC)
-            arrs = {n: jax.device_put(a, sharding) for n, a in arrs.items()}
-        out[gname] = arrs
+                cols = sharding.addressable_devices_indices_map(
+                    shapes[name])[dev][2]
+                shards[name].append(jax.device_put(
+                    by_rank[rank][name][:, :, cols], dev))
+        out[gname] = {
+            name: jax.make_array_from_single_device_arrays(
+                shapes[name], sharding, shards[name])
+            for name in specs}
     return out

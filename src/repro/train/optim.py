@@ -43,7 +43,10 @@ def init_opt_state(params: Any, cfg: OptimConfig,
                    grad_ef: bool = False, qgrad_ef: bool = False,
                    fsdp: int = 1) -> Dict[str, Any]:
     dt = jnp.dtype(cfg.moment_dtype)
-    zeros = lambda p: jnp.zeros(p.shape, dt)
+    # each state leaf is made where its parameter lives (sharded over
+    # the mesh), never whole on the default device
+    where = lambda p: getattr(p, "sharding", None)
+    zeros = lambda p: jnp.zeros(p.shape, dt, device=where(p))
     state = {"m": jax.tree_util.tree_map(zeros, params),
              "v": jax.tree_util.tree_map(zeros, params),
              "step": jnp.zeros((), jnp.int32)}
@@ -51,7 +54,7 @@ def init_opt_state(params: Any, cfg: OptimConfig,
         # error-feedback residual for the compressed grad AllReduce:
         # lives with the optimizer state (same ZeRO sharding as the
         # grads it corrects), donated and checkpointed alongside m/v
-        ef = lambda p: jnp.zeros(p.shape, jnp.float32)
+        ef = lambda p: jnp.zeros(p.shape, jnp.float32, device=where(p))
         state["ef"] = jax.tree_util.tree_map(ef, params)
     if qgrad_ef:
         # error-feedback residual for the quantized gradient RS over
@@ -60,7 +63,8 @@ def init_opt_state(params: Any, cfg: OptimConfig,
         # sharded over ``data`` so the per-rank view matches the
         # full-length delta gradients (see train_step.py)
         qef = lambda p: jnp.zeros(
-            (p.shape[0], p.shape[1], p.shape[2] * fsdp), jnp.float32)
+            (p.shape[0], p.shape[1], p.shape[2] * fsdp), jnp.float32,
+            device=where(p))
         state["qef"] = jax.tree_util.tree_map(qef, params)
     return state
 

@@ -16,9 +16,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core.policy import CommPolicy
 from repro.models.config import ModelConfig
+from repro.models.layers import vocab_parallel_logits
 from repro.models.model import (forward, greedy_next_token, init_caches,
                                 param_groups)
 from repro.parallel.plan import ShardingPlan
@@ -26,10 +26,32 @@ from repro.parallel.shardings import STORE_SPEC, store_spec
 from repro.train.train_step import batch_spec
 
 
+def _head(hidden, unemb, cfg: ModelConfig, plan: ShardingPlan,
+          logits: bool):
+    """Greedy next token (B,), plus with ``logits`` the last-position
+    vocab-parallel logits (B, v_loc); padded vocab columns are -inf."""
+    nt = greedy_next_token(hidden, unemb, cfg, plan)
+    if not logits:
+        return nt
+    lg = vocab_parallel_logits(hidden[:, -1], unemb, cfg.logit_softcap)
+    col = (jnp.arange(plan.v_loc)[None, :]
+           + jax.lax.axis_index("model") * plan.v_loc)
+    return nt, jnp.where(col < cfg.vocab, lg, -jnp.inf)
+
+
+def _logit_spec(bspec) -> P:
+    return P(bspec[0] if len(bspec) else None, "model")
+
+
 def make_prefill(cfg: ModelConfig, plan: ShardingPlan, policy: CommPolicy,
                  mesh, global_batch: int,
-                 window_override: Optional[int] = None):
-    """Full-sequence forward -> next token at the last position (B,)."""
+                 window_override: Optional[int] = None,
+                 logits: bool = False):
+    """Full-sequence forward -> next token at the last position (B,).
+
+    With ``logits`` it returns ``(token, logits)``: the last-position
+    logits (B, tp * v_loc), vocab sharded over ``model``.
+    """
     dtype = jnp.dtype(cfg.dtype)
     bspec = batch_spec(global_batch, mesh)
 
@@ -38,14 +60,15 @@ def make_prefill(cfg: ModelConfig, plan: ShardingPlan, policy: CommPolicy,
             store, batch["tokens"], cfg, plan, policy,
             enc_embeds=batch.get("enc_embeds"),
             window_override=window_override, dtype=dtype)
-        return greedy_next_token(hidden, unemb, cfg, plan)
+        return _head(hidden, unemb, cfg, plan, logits)
 
     bs = {"tokens": bspec}
     if cfg.is_enc_dec or cfg.has_cross:
         bs["enc_embeds"] = bspec
-    sm = compat.shard_map(prefill, mesh=mesh,
+    out_specs = (bspec, _logit_spec(bspec)) if logits else bspec
+    sm = jax.shard_map(prefill, mesh=mesh,
                        in_specs=(store_spec(plan), bs),
-                       out_specs=bspec, check_vma=False)
+                       out_specs=out_specs, check_vma=False)
     return jax.jit(sm)
 
 
@@ -138,8 +161,13 @@ def decode_cache_specs(cfg: ModelConfig, plan: ShardingPlan, mesh,
 def make_decode_step(cfg: ModelConfig, plan: ShardingPlan,
                      policy: CommPolicy, mesh, global_batch: int,
                      cache_len: int,
-                     window_override: Optional[int] = None):
-    """serve_step: (store, caches, batch) -> (next (B,), new caches)."""
+                     window_override: Optional[int] = None,
+                     logits: bool = False):
+    """serve_step: (store, caches, batch) -> (next (B,), new caches).
+
+    With ``logits`` the first output is ``(next, logits)`` as in
+    :func:`make_prefill`.
+    """
     dtype = jnp.dtype(cfg.dtype)
     bspec = batch_spec(global_batch, mesh)
     _, cache_specs = decode_cache_specs(cfg, plan, mesh, global_batch,
@@ -150,15 +178,15 @@ def make_decode_step(cfg: ModelConfig, plan: ShardingPlan,
             store, batch["tokens"], cfg, plan, policy,
             enc_embeds=batch.get("enc_embeds"), caches=caches,
             window_override=window_override, dtype=dtype)
-        nt = greedy_next_token(hidden, unemb, cfg, plan)
-        return nt, new_caches
+        return _head(hidden, unemb, cfg, plan, logits), new_caches
 
     bs = {"tokens": bspec}
     if cfg.is_enc_dec or cfg.has_cross:
         bs["enc_embeds"] = bspec
-    sm = compat.shard_map(step, mesh=mesh,
+    head_spec = (bspec, _logit_spec(bspec)) if logits else bspec
+    sm = jax.shard_map(step, mesh=mesh,
                        in_specs=(store_spec(plan), cache_specs, bs),
-                       out_specs=(bspec, cache_specs), check_vma=False)
+                       out_specs=(head_spec, cache_specs), check_vma=False)
     return jax.jit(sm, donate_argnums=(1,))
 
 
@@ -173,6 +201,6 @@ def make_cache_init(cfg: ModelConfig, plan: ShardingPlan, mesh,
     def init():
         return init_caches(cfg, plan, b_loc, cache_len, dtype)
 
-    sm = compat.shard_map(init, mesh=mesh, in_specs=(),
+    sm = jax.shard_map(init, mesh=mesh, in_specs=(),
                        out_specs=cache_specs, check_vma=False)
     return jax.jit(sm)

@@ -33,7 +33,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core.collectives import (compressed_psum, compressed_psum_ef,
                                     quantized_reduce_scatter,
                                     quantized_reduce_scatter_ef)
@@ -82,9 +81,9 @@ def make_loss_fn(cfg: ModelConfig, plan: ShardingPlan, policy: CommPolicy,
         return lm_loss(hidden, unemb, labels, cfg, plan, aux, aux_weight)
 
     def loss_fn(views, deltas, batch):
-        denom = compat.axis_size("model") * compat.axis_size("data")
+        denom = jax.lax.axis_size("model") * jax.lax.axis_size("data")
         if multi_pod:
-            denom *= compat.axis_size("pod")
+            denom *= jax.lax.axis_size("pod")
         tokens, labels = batch["tokens"], batch["labels"]
         enc = batch.get("enc_embeds")
         if n_micro == 1:
@@ -296,7 +295,7 @@ def make_train_step(cfg: ModelConfig, plan: ShardingPlan,
         # (per-rank view matches the full-length delta grads)
         opt_spec["qef"] = STORE_SPEC
 
-    sm = compat.shard_map(
+    sm = jax.shard_map(
         step, mesh=mesh,
         in_specs=(STORE_SPEC, opt_spec, bs),
         out_specs=(STORE_SPEC, opt_spec, metric_spec),

@@ -17,7 +17,6 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro import compat  # noqa: E402
 from repro.core import (compressed_psum, default_comm_config,  # noqa: E402
                         dispatch_all_to_all)
 from repro.core.codec import qdq_wire  # noqa: E402
@@ -32,7 +31,7 @@ def check_quantized_ar():
         for bits in (8, 5, 2):
             cfg = default_comm_config(bits, scheme=scheme)
 
-            @partial(compat.shard_map, mesh=mesh,
+            @partial(jax.shard_map, mesh=mesh,
                      in_specs=P(("pod", "data", "model")),
                      out_specs=P(("pod", "data", "model")),
                      check_vma=False)
@@ -75,7 +74,7 @@ def check_framed_bridge():
             bridge = CommConfig(bits=8, group=128, scheme=scheme,
                                 framed=framed)   # pod tier: 8-bit
 
-            @partial(compat.shard_map, mesh=mesh,
+            @partial(jax.shard_map, mesh=mesh,
                      in_specs=P(("pod", "data", "model")),
                      out_specs=P(("pod", "data", "model")),
                      check_vma=False)
@@ -114,7 +113,7 @@ def check_fused_ar():
             cfg = CommConfig(bits=bits, group=32, spike=spike,
                              scale_int=scale_int, scheme=scheme)
 
-            @partial(compat.shard_map, mesh=mesh,
+            @partial(jax.shard_map, mesh=mesh,
                      in_specs=P(("data", "model")),
                      out_specs=P(("data", "model")), check_vma=False)
             def f(xs):
@@ -146,7 +145,7 @@ def check_fused_a2a():
         for scheme in ("two_step", "fused"):
             cfg = CommConfig(scheme=scheme, **cfg_kw)
 
-            @partial(compat.shard_map, mesh=mesh, in_specs=P("model"),
+            @partial(jax.shard_map, mesh=mesh, in_specs=P("model"),
                      out_specs=P("model"), check_vma=False)
             def g(xs):
                 return dispatch_all_to_all(xs[0], "model", cfg)[None]
@@ -193,7 +192,7 @@ def check_a2a_semantics():
     xa = jax.random.normal(jax.random.PRNGKey(2), (4, 4, 2, 128),
                            jnp.float32)
 
-    @partial(compat.shard_map, mesh=mesh, in_specs=P("model"),
+    @partial(jax.shard_map, mesh=mesh, in_specs=P("model"),
              out_specs=P("model"), check_vma=False)
     def g(xs):
         return dispatch_all_to_all(xs[0], "model", cfg)[None]
@@ -356,7 +355,7 @@ def check_ep_slice():
     def run(ep_slice):
         pol = dataclasses.replace(BF16_POLICY, ep_slice=ep_slice)
 
-        @partial(compat.shard_map, mesh=mesh, in_specs=(P(),) * 5,
+        @partial(jax.shard_map, mesh=mesh, in_specs=(P(),) * 5,
                  out_specs=P(), check_vma=False)
         def f_(W1g, W2g, W3g, Rg, xg):
             rank = lax.axis_index("model")

@@ -10,7 +10,6 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import codec
 from repro.core.collectives import compressed_psum
 from repro.core.comm_config import BIT_UNITS, CommConfig, \
@@ -117,7 +116,7 @@ def test_compressed_psum_identical_across_backends():
 
         def f(xs):
             return compressed_psum(xs, ("model",), cfg)
-        sm = compat.shard_map(f, mesh=mesh, in_specs=P("model"),
+        sm = jax.shard_map(f, mesh=mesh, in_specs=P("model"),
                               out_specs=P("model"), check_vma=False)
         return np.asarray(jax.jit(sm)(x))
 
@@ -136,7 +135,7 @@ def test_policy_with_backend_end_to_end():
     def run(cfg):
         def f(xs):
             return compressed_psum(xs, ("model",), cfg)
-        sm = compat.shard_map(f, mesh=mesh, in_specs=P("model"),
+        sm = jax.shard_map(f, mesh=mesh, in_specs=P("model"),
                               out_specs=P("model"), check_vma=False)
         return np.asarray(jax.jit(sm)(x))
 

@@ -30,7 +30,6 @@ import pytest
 from _hyp import given, settings, st
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import (compressed_psum, default_comm_config,
                         dispatch_all_to_all)
 from repro.core.codec import qdq_wire
@@ -58,7 +57,7 @@ def _mesh4():
 
 
 def _psum_all_axes(x, cfg, mesh):
-    @functools.partial(compat.shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=P(("pod", "data", "model")),
                        out_specs=P(("pod", "data", "model")),
                        check_vma=False)
@@ -103,7 +102,7 @@ def test_compressed_psum_grad_exact(bits, scheme):
     cfg = default_comm_config(bits, scheme=scheme)
 
     def grad_of(c):
-        @functools.partial(compat.shard_map, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=mesh,
                            in_specs=P(("pod", "model")),
                            out_specs=P(("pod", "model")),
                            check_vma=False)
@@ -144,7 +143,7 @@ def test_a2a_pads_non_group_multiples(d, bits):
     x = jax.random.normal(jax.random.PRNGKey(d), (1, 3, d),
                           jnp.float32) * 2
 
-    @functools.partial(compat.shard_map, mesh=mesh, in_specs=P("model"),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("model"),
                        out_specs=P("model"), check_vma=False)
     def f(xs):
         return quantized_all_to_all(xs, "model", cfg)
@@ -167,7 +166,7 @@ def test_a2a_pad_multidevice_semantics():
     xa = jax.random.normal(jax.random.PRNGKey(2), (4, 4, 2, d),
                            jnp.float32)
 
-    @functools.partial(compat.shard_map, mesh=mesh, in_specs=P("model"),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("model"),
                        out_specs=P("model"), check_vma=False)
     def g(xs):
         return dispatch_all_to_all(xs[0], "model", cfg)[None]
@@ -198,7 +197,7 @@ def test_a2a_edge_shapes_fused_lockstep(d, m, bits):
     for scheme in ("two_step", "fused"):
         cfg = default_comm_config(bits, scheme=scheme)
 
-        @functools.partial(compat.shard_map, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=mesh,
                            in_specs=P("model"), out_specs=P("model"),
                            check_vma=False)
         def g(xs):
@@ -243,7 +242,7 @@ def test_quantized_all_gather_conformance(bits, scale_int):
     x = _per_rank_x(100 + bits)
     cfg = default_comm_config(bits, scale_int=scale_int)
 
-    @functools.partial(compat.shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=P(("pod", "model")),
                        out_specs=P(("pod", "model")), check_vma=False)
     def f(xs):
@@ -283,7 +282,7 @@ def test_quantized_reduce_scatter_error_bounded(bits, scale_int):
     x = _per_rank_x(200 + bits)
     cfg = default_comm_config(bits, scale_int=scale_int)
 
-    @functools.partial(compat.shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=P(("pod", "model")),
                        out_specs=P(("pod", "model")), check_vma=False)
     def f(xs):
@@ -316,7 +315,7 @@ def test_quantized_all_gather_grad_exact(bits):
     cfg = default_comm_config(bits)
 
     def grad_of(gather):
-        @functools.partial(compat.shard_map, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=mesh,
                            in_specs=P(("pod", "model")),
                            out_specs=P(("pod", "model")),
                            check_vma=False)
@@ -348,7 +347,7 @@ def test_quantized_reduce_scatter_grad_exact(bits):
     cfg = default_comm_config(bits)
 
     def grad_of(scatter):
-        @functools.partial(compat.shard_map, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=mesh,
                            in_specs=P(("pod", "model")),
                            out_specs=P(("pod", "model")),
                            check_vma=False)

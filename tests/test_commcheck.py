@@ -147,7 +147,7 @@ def test_wire_layout_is_a_partition(bits, group, spike, scale_int, groups):
     assert layout.check_layout(lay, "prop") == []
 
 
-@settings(max_examples=40)
+@settings(max_examples=40, deadline=None)
 @given(bits=st.integers(min_value=1, max_value=8),
        group=st.sampled_from([32, 128]),
        spike=st.booleans(), scale_int=st.booleans())

@@ -9,7 +9,6 @@ import pytest
 from functools import partial
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs import get_smoke_config
 from repro.core.policy import BF16_POLICY
 from repro.launch.mesh import make_test_mesh
@@ -25,7 +24,7 @@ def _last_logits_full(cfg, plan, store, mesh, toks, enc=None):
                                       BF16_POLICY, enc_embeds=enc_embeds,
                                       dtype=jnp.float32)
         return vocab_parallel_logits(hidden[:, -1], unemb)
-    sm = compat.shard_map(f, mesh=mesh, in_specs=(STORE_SPEC, P(), P()),
+    sm = jax.shard_map(f, mesh=mesh, in_specs=(STORE_SPEC, P(), P()),
                        out_specs=P(None, "model"), check_vma=False)
     return np.asarray(jax.jit(sm)(store, toks, enc))
 
@@ -43,9 +42,9 @@ def _last_logits_decode(cfg, plan, store, mesh, toks, enc=None):
     def init():
         return init_caches(cfg, plan, b, s, jnp.float32)
     cspec = jax.tree_util.tree_map(lambda _: P(), jax.eval_shape(init))
-    caches = jax.jit(compat.shard_map(init, mesh=mesh, in_specs=(),
+    caches = jax.jit(jax.shard_map(init, mesh=mesh, in_specs=(),
                                    out_specs=cspec, check_vma=False))()
-    sm = jax.jit(compat.shard_map(
+    sm = jax.jit(jax.shard_map(
         step, mesh=mesh, in_specs=(STORE_SPEC, cspec, P(), P()),
         out_specs=(P(None, "model"), cspec), check_vma=False))
     out = None
@@ -102,7 +101,7 @@ def test_moe_identical_experts_equals_dense():
     def f_moe(p, x):
         out, aux = moe_mod.moe_apply(p, x, cfg, plan, BF16_POLICY)
         return out
-    sm = compat.shard_map(f_moe, mesh=mesh, in_specs=(P(), P()),
+    sm = jax.shard_map(f_moe, mesh=mesh, in_specs=(P(), P()),
                        out_specs=P(), check_vma=False)
     out = np.asarray(jax.jit(sm)(p, x))
     h = np.asarray(x) @ w1

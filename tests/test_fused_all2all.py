@@ -16,7 +16,6 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import codec, default_comm_config, dispatch_all_to_all
 from repro.core.collectives import padded_len, quantized_all_to_all
 from repro.core.comm_config import CommConfig
@@ -39,7 +38,7 @@ def test_emulated_a2a_blocks_are_codec_qdq(spike, scale_int):
     mesh = make_test_mesh(data=1, model=1)
     x = _x(seed=3)
 
-    @functools.partial(compat.shard_map, mesh=mesh, in_specs=P("model"),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("model"),
                        out_specs=P("model"), check_vma=False)
     def f(xs):
         return emulate.fused_all_to_all_emulated(xs, "model", cfg)
@@ -64,7 +63,7 @@ def test_fused_matches_xla_single_device(bits, dtype):
     def run(scheme):
         cfg = default_comm_config(bits, scheme=scheme)
 
-        @functools.partial(compat.shard_map, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=mesh,
                            in_specs=P("model"), out_specs=P("model"),
                            check_vma=False)
         def f(xs):
@@ -84,7 +83,7 @@ def test_fused_pad_path_single_device(d):
     cfg = default_comm_config(4, scheme="fused")     # group 32
     x = _x(shape=(1, 2, d), seed=d)
 
-    @functools.partial(compat.shard_map, mesh=mesh, in_specs=P("model"),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("model"),
                        out_specs=P("model"), check_vma=False)
     def f(xs):
         return quantized_all_to_all(xs, "model", cfg)
@@ -106,7 +105,7 @@ def test_nccl_scheme_bypasses_codec():
     cfg = CommConfig(bits=2, group=32, scheme="nccl")
     x = _x(seed=9)
 
-    @functools.partial(compat.shard_map, mesh=mesh, in_specs=P("model"),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("model"),
                        out_specs=P("model"), check_vma=False)
     def f(xs):
         return quantized_all_to_all(xs, "model", cfg)
@@ -124,7 +123,7 @@ def test_dispatch_vjp_stays_bf16_combine():
     cfg = default_comm_config(2, scheme="fused")     # harshest forward
     x = _x(shape=(1, 2, 64), seed=11)
 
-    @functools.partial(compat.shard_map, mesh=mesh, in_specs=P("model"),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("model"),
                        out_specs=P("model"), check_vma=False)
     def g(xs):
         def loss(xr):
@@ -156,7 +155,7 @@ def test_dispatcher_uses_emulation_off_tpu():
     cfg = default_comm_config(8, scheme="fused")
     x = _x(shape=(1, 2, D), seed=1)
 
-    @functools.partial(compat.shard_map, mesh=mesh, in_specs=P("model"),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("model"),
                        out_specs=P("model"), check_vma=False)
     def f(xs):
         return ops.fused_all_to_all(xs, "model", cfg)

@@ -14,10 +14,9 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import codec, compressed_psum, default_comm_config
 from repro.core.comm_config import CommConfig
-from repro.kernels import emulate
+from repro.kernels import emulate, ops
 from repro.launch.mesh import make_test_mesh
 
 N = 512
@@ -66,7 +65,7 @@ def test_fused_matches_two_step_single_device(bits):
     def run(scheme):
         cfg = default_comm_config(bits, scheme=scheme)
 
-        @functools.partial(compat.shard_map, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=mesh,
                            in_specs=P("model"), out_specs=P("model"),
                            check_vma=False)
         def f(xs):
@@ -91,7 +90,7 @@ def test_fused_matches_two_step_multidevice(bits, spike, scale_int):
         cfg = CommConfig(bits=bits, group=32, spike=spike,
                          scale_int=scale_int, scheme=scheme)
 
-        @functools.partial(compat.shard_map, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=mesh,
                            in_specs=P(("data", "model")),
                            out_specs=P(("data", "model")),
                            check_vma=False)
@@ -108,10 +107,10 @@ def test_mesh_axis_names_ambient():
     mesh = make_test_mesh(data=1, model=1)
     seen = {}
 
-    @functools.partial(compat.shard_map, mesh=mesh, in_specs=P(),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P(),
                        out_specs=P(), check_vma=False)
     def f(xs):
-        seen["names"] = compat.mesh_axis_names()
+        seen["names"] = ops.mesh_axis_names()
         return xs
 
     f(jnp.zeros((4,)))
@@ -137,7 +136,7 @@ def test_dispatcher_uses_emulation_off_tpu():
     cfg = default_comm_config(8, scheme="fused")
     x = _x(shape=(640,), seed=1)
 
-    @functools.partial(compat.shard_map, mesh=mesh, in_specs=P(),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P(),
                        out_specs=P(), check_vma=False)
     def f(xs):
         return ops.fused_all_reduce(xs, "model", cfg)

@@ -23,7 +23,6 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from _hyp import given, settings, st
-from repro import compat
 from repro.core.codec import qdq_wire
 from repro.core.collectives import compressed_psum, compressed_psum_ef
 from repro.core.comm_config import (CommConfig, NO_COMPRESSION,
@@ -235,12 +234,12 @@ def _ef_stream_errors(bits, steps=16, n=512):
     mesh = make_test_mesh(data=1, model=1)
     cfg = default_comm_config(bits)
 
-    @partial(compat.shard_map, mesh=mesh, in_specs=(P(), P()),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P(), P()),
              out_specs=(P(), P()), check_vma=False)
     def step_ef(g, e):
         return compressed_psum_ef(g, e, ("model",), cfg)
 
-    @partial(compat.shard_map, mesh=mesh, in_specs=P(),
+    @partial(jax.shard_map, mesh=mesh, in_specs=P(),
              out_specs=P(), check_vma=False)
     def step_plain(g):
         return compressed_psum(g, ("model",), cfg)
@@ -288,7 +287,7 @@ def test_ef_residual_is_local_qdq_error():
     x = jnp.asarray(np.random.default_rng(1).standard_normal(256),
                     jnp.float32)
 
-    @partial(compat.shard_map, mesh=mesh, in_specs=(P(), P()),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P(), P()),
              out_specs=(P(), P()), check_vma=False)
     def f(g, e):
         return compressed_psum_ef(g, e, ("model",), cfg)
@@ -309,7 +308,7 @@ def test_ef_psum_grad_exact():
                     jnp.float32)
     e0 = jnp.zeros_like(x)
 
-    @partial(compat.shard_map, mesh=mesh, in_specs=(P(), P()),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P(), P()),
              out_specs=P(), check_vma=False)
     def loss_sm(g, e):
         out, _ = compressed_psum_ef(g, e, ("model",), cfg)
@@ -329,7 +328,7 @@ def test_ef_reduce_scatter_residual():
     x = jnp.asarray(np.random.default_rng(4).standard_normal(256),
                     jnp.float32)
 
-    @partial(compat.shard_map, mesh=mesh, in_specs=(P(), P()),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P(), P()),
              out_specs=(P(), P()), check_vma=False)
     def f(g, e):
         return quantized_reduce_scatter_ef(g, e, "model", cfg)
@@ -340,7 +339,7 @@ def test_ef_reduce_scatter_residual():
     np.testing.assert_allclose(np.asarray(res), np.asarray(x) - qdq,
                                atol=1e-6)
     # grad: exact all_gather transpose for both inputs
-    @partial(compat.shard_map, mesh=mesh, in_specs=(P(), P()),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P(), P()),
              out_specs=P(), check_vma=False)
     def loss_sm(g, e):
         out, _ = quantized_reduce_scatter_ef(g, e, "model", cfg)
@@ -355,7 +354,7 @@ def test_ef_disabled_site_passthrough():
     x = jnp.arange(64, dtype=jnp.float32)
     e0 = jnp.full((64,), 0.5, jnp.float32)
 
-    @partial(compat.shard_map, mesh=mesh, in_specs=(P(), P()),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P(), P()),
              out_specs=(P(), P()), check_vma=False)
     def f(g, e):
         return compressed_psum_ef(g, e, ("model",), NO_COMPRESSION)
@@ -401,7 +400,7 @@ def test_single_axis_hier_pp_pipelines():
     x = jnp.asarray(np.random.default_rng(3).standard_normal(n),
                     jnp.float32)
 
-    @partial(compat.shard_map, mesh=mesh, in_specs=P(), out_specs=P(),
+    @partial(jax.shard_map, mesh=mesh, in_specs=P(), out_specs=P(),
              check_vma=False)
     def f(g):
         return compressed_psum(g, ("model",), cfg)

@@ -55,3 +55,22 @@ def test_train_launcher_cli(tmp_path):
     assert r.returncode == 0, r.stderr[-2000:]
     assert os.path.exists(ck)
     assert "loss" in r.stdout
+
+
+def test_serve_run_matches_main():
+    """``serve.run`` on a config built by the caller gives the tokens
+    ``serve.main`` gives for the same arguments (same process, so the
+    same seeded weights)."""
+    import numpy as np
+
+    from repro.configs import get_smoke_config
+    from repro.launch import serve
+    argv = ["--arch", "qwen3-14b", "--smoke", "--batch", "2",
+            "--prompt-len", "8", "--gen", "3", "--codec-backend", "pallas"]
+    via_main = serve.main(argv)
+    via_run = serve.run(get_smoke_config("qwen3-14b"),
+                        serve.parse_args(argv))
+    np.testing.assert_array_equal(via_run["first"], via_main["first"])
+    np.testing.assert_array_equal(via_run["generated"],
+                                  via_main["generated"])
+    assert via_run["generated"].shape == (2, 3)
