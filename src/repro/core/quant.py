@@ -16,13 +16,73 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 
 _EPS = 1e-12
+_HI16 = np.uint32(0xFFFF0000)      # the bf16 bits of an f32
 
 
 def group_min_max(xg: jnp.ndarray, axis: int = -1):
     """(min, max) over the in-group ``axis``."""
     return jnp.min(xg, axis=axis), jnp.max(xg, axis=axis)
+
+
+def cast(x: jnp.ndarray, dtype) -> jnp.ndarray:
+    """f32 ``x`` to ``dtype``, rounding to nearest even. To bfloat16 the
+    rounding is integer arithmetic on the f32 bits: the TPU Pallas
+    compiler's own f32 -> bf16 conversion does not round like XLA's, and
+    every backend must produce the same wire bytes."""
+    if jnp.dtype(dtype) != jnp.bfloat16:
+        return x.astype(dtype)
+    bits = lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
+    bits = (bits + ((bits >> 16) & 1) + 0x7FFF) & _HI16
+    r = lax.bitcast_convert_type(bits, jnp.float32)     # exactly bf16
+    return jnp.where(jnp.isnan(x), x, r).astype(jnp.bfloat16)
+
+
+def _half_ulp(m: jnp.ndarray) -> jnp.ndarray:
+    """Half an f32 ulp of ``|m|`` (normal, nonzero), as a power of two."""
+    e = (lax.bitcast_convert_type(jnp.abs(m), jnp.uint32) >> 23) & 0xFF
+    return lax.bitcast_convert_type((e - 24) << 23, jnp.float32)
+
+
+def round_codes(d: jnp.ndarray, s: jnp.ndarray, qmax: float) -> jnp.ndarray:
+    """``clip(round(d / s), 0, qmax)`` as int32, for a bf16-exact ``s``.
+
+    ``round`` of the correctly rounded f32 quotient, reproduced from any
+    backend's division: the candidate tie point ``t = (k + .5) * s`` is
+    exact in f32 (at most 10 + 8 significant bits), so ``d - t`` decides
+    exactly which side of it the quotient lies, and whether the f32
+    quotient would have landed on the tie (round half to even).
+    """
+    q = d / s
+    k = jnp.floor(jnp.clip(q, -1.0, qmax + 1.0))
+    diff = d - (k + 0.5) * s                            # exact near a tie
+    tie = jnp.abs(diff) <= _half_ulp(k + 0.5) * s
+    odd = k - 2.0 * jnp.floor(k * 0.5) == 1.0
+    up = (tie & odd) | (~tie & (diff > 0))      # no i1 select on TPU
+    code = jnp.where(jnp.isnan(q), q, k + up.astype(jnp.float32))
+    return jnp.clip(code, 0.0, qmax).astype(jnp.int32)
+
+
+def meta_scale(rng: jnp.ndarray, qmax: float, meta_dtype) -> jnp.ndarray:
+    """``max(rng / qmax, EPS)`` in ``meta_dtype`` (f32 quotient, then
+    round to nearest even), the same on every backend: for bf16 the
+    midpoint ``m`` between the two bf16 candidates times ``qmax`` is
+    exact in f32, so ``rng - m * qmax`` places the quotient exactly."""
+    q = rng / qmax
+    if jnp.dtype(meta_dtype) != jnp.bfloat16:
+        return jnp.maximum(q, _EPS).astype(meta_dtype)
+    lo = lax.bitcast_convert_type(q, jnp.uint32) & _HI16
+    m = lax.bitcast_convert_type(lo + 0x8000, jnp.float32)
+    diff = rng - m * qmax                               # exact near a tie
+    tie = jnp.abs(diff) <= _half_ulp(m) * qmax
+    up = (tie & ((lo >> 16) & 1 == 1)) | (~tie & (diff > 0))
+    r = lax.bitcast_convert_type(lo + up.astype(jnp.uint32) * 0x10000,
+                                 jnp.float32)
+    r = jnp.where(jnp.isfinite(q), r, q)
+    return cast(jnp.where(q < _EPS, jnp.float32(_EPS), r), jnp.bfloat16)
 
 
 def group_reshape(x: jnp.ndarray, group: int) -> jnp.ndarray:
@@ -45,15 +105,13 @@ def quantize_groups(xg: jnp.ndarray, bits: int, meta_dtype=jnp.bfloat16,
     """
     qmax = float(2 ** bits - 1)
     mn, mx = group_min_max(xg, axis)
-    scale = (mx - mn) / qmax
     # Store meta at wire precision, then quantize *with the stored values*
     # so encode/decode are self-consistent.
-    scale_w = jnp.maximum(scale, _EPS).astype(meta_dtype)
-    zero_w = mn.astype(meta_dtype)
+    scale_w = meta_scale(mx - mn, qmax, meta_dtype)
+    zero_w = cast(mn, meta_dtype)
     s = jnp.expand_dims(scale_w.astype(jnp.float32), axis)
     z = jnp.expand_dims(zero_w.astype(jnp.float32), axis)
-    codes = jnp.clip(jnp.round((xg - z) / s), 0.0, qmax).astype(jnp.int32)
-    return codes, scale_w, zero_w
+    return round_codes(xg - z, s, qmax), scale_w, zero_w
 
 
 def dequantize_groups(codes: jnp.ndarray, scale: jnp.ndarray,
@@ -80,7 +138,7 @@ def dequantize(codes: jnp.ndarray, scale: jnp.ndarray, zero: jnp.ndarray,
                out_dtype=jnp.float32) -> jnp.ndarray:
     """Inverse of :func:`quantize`; returns the flat (..., n) tensor."""
     xg = dequantize_groups(codes, scale, zero)
-    return group_unreshape(xg).astype(out_dtype)
+    return cast(group_unreshape(xg), out_dtype)
 
 
 def qdq(x: jnp.ndarray, bits: int, group: int,

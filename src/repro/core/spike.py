@@ -51,7 +51,8 @@ from typing import NamedTuple
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core.quant import group_reshape, group_unreshape
+from repro.core.quant import (cast, group_reshape, group_unreshape,
+                              meta_scale, round_codes)
 
 _EPS = 1e-12
 
@@ -124,9 +125,8 @@ def spike_quantize_groups(xg: jnp.ndarray, bits: int,
     mn = jnp.where(all_dropped, jnp.float32(jnp.nan), mn)
     mx = jnp.where(all_dropped, jnp.float32(jnp.nan), mx)
 
-    scale = (mx - mn) / qmax
-    scale_w = jnp.maximum(scale, _EPS).astype(meta_dtype)
-    zero_w = mn.astype(meta_dtype)
+    scale_w = meta_scale(mx - mn, qmax, meta_dtype)
+    zero_w = cast(mn, meta_dtype)
     s = scale_w.astype(jnp.float32)
     z = zero_w.astype(jnp.float32)
     # Spike slots are set to the new minimum before quantization (paper:
@@ -134,9 +134,8 @@ def spike_quantize_groups(xg: jnp.ndarray, bits: int,
     # overwritten on dequant. Quantizing xg everywhere and patching the
     # spike slots with the (per-group) code of `mn` afterwards is the
     # same arithmetic per element.
-    codes = jnp.clip(jnp.round((xg - ex(z)) / ex(s)),
-                     0.0, qmax).astype(jnp.int32)
-    code_mn = jnp.clip(jnp.round((mn - z) / s), 0.0, qmax).astype(jnp.int32)
+    codes = round_codes(xg - ex(z), ex(s), qmax)
+    code_mn = round_codes(mn - z, s, qmax)
     codes = jnp.where(min_mask | max_mask, ex(code_mn), codes)
     return codes, scale_w, zero_w, (vmin, vmax), (imin, imax)
 
@@ -163,7 +162,7 @@ def spike_quantize(x: jnp.ndarray, bits: int, group: int,
                    meta_dtype=jnp.bfloat16) -> SpikeQuant:
     codes, scale_w, zero_w, vals, idx = spike_quantize_groups(
         group_reshape(x.astype(jnp.float32), group), bits, meta_dtype)
-    spike_vals = jnp.stack(vals, axis=-1).astype(meta_dtype)
+    spike_vals = cast(jnp.stack(vals, axis=-1), meta_dtype)
     spike_idx = jnp.stack(idx, axis=-1).astype(jnp.int8)
     return SpikeQuant(codes.astype(jnp.uint8), scale_w, zero_w,
                       spike_vals, spike_idx)
@@ -174,7 +173,7 @@ def spike_dequantize(q: SpikeQuant, out_dtype=jnp.float32) -> jnp.ndarray:
     xg = spike_dequantize_groups(
         codes, scale, zero, (spike_vals[..., 0], spike_vals[..., 1]),
         (spike_idx[..., 0], spike_idx[..., 1]))
-    return group_unreshape(xg).astype(out_dtype)
+    return cast(group_unreshape(xg), out_dtype)
 
 
 def spike_qdq(x: jnp.ndarray, bits: int, group: int,

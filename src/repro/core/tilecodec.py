@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from repro.core import rotation as rot
 from repro.core import scale_codec, wordpack
 from repro.core.comm_config import WireLayout, _wire_layout
-from repro.core.quant import dequantize_groups, quantize_groups
+from repro.core.quant import cast, dequantize_groups, quantize_groups
 from repro.core.spike import spike_dequantize_groups, spike_quantize_groups
 
 
@@ -133,7 +133,7 @@ def encode_sections(x: jnp.ndarray, *, bits: int, group: int, n: int,
         # per group [min, max]: values exact at meta width, indices
         # int8 with scale_int (all < 128), meta-width otherwise
         sv = jnp.stack(vals, axis=1).reshape(2 * g, rows)
-        out.append((layout.spike_vals, _meta_bytes(sv.astype(meta_dtype))))
+        out.append((layout.spike_vals, _meta_bytes(cast(sv, meta_dtype))))
         si = jnp.stack(idx, axis=1).reshape(2 * g, rows)
         out.append((layout.spike_idx, si if scale_int
                     else _meta_bytes(si.astype(meta_dtype))))
@@ -204,4 +204,4 @@ def decode_tile(wire: jnp.ndarray, *, bits: int, group: int, n: int,
     out = xg.reshape(n, rows).T
     if rotation:
         out = rot.unrotate(out, group)
-    return out.astype(out_dtype)
+    return cast(out, out_dtype)
