@@ -11,8 +11,14 @@ the group on a leading axis of a transposed tile, where the group
 reduction is a sublane reduction on TPU. The min and max are two plain
 reductions (a variadic ``lax.reduce`` has no Pallas TPU lowering); both
 propagate NaN like ``jnp.min``/``jnp.max``.
+
+The rounding helpers (:func:`cast`, :func:`round_codes`,
+:func:`meta_scale`) are jitted: an eager caller, such as an un-jitted
+``shard_map``, then dispatches each as one program, not op by op.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +34,7 @@ def group_min_max(xg: jnp.ndarray, axis: int = -1):
     return jnp.min(xg, axis=axis), jnp.max(xg, axis=axis)
 
 
+@functools.partial(jax.jit, static_argnums=1)
 def cast(x: jnp.ndarray, dtype) -> jnp.ndarray:
     """f32 ``x`` to ``dtype``, rounding to nearest even. To bfloat16 the
     rounding is integer arithmetic on the f32 bits: the TPU Pallas
@@ -47,6 +54,7 @@ def _half_ulp(m: jnp.ndarray) -> jnp.ndarray:
     return lax.bitcast_convert_type((e - 24) << 23, jnp.float32)
 
 
+@functools.partial(jax.jit, static_argnums=2)
 def round_codes(d: jnp.ndarray, s: jnp.ndarray, qmax: float) -> jnp.ndarray:
     """``clip(round(d / s), 0, qmax)`` as int32, for a bf16-exact ``s``.
 
@@ -66,6 +74,7 @@ def round_codes(d: jnp.ndarray, s: jnp.ndarray, qmax: float) -> jnp.ndarray:
     return jnp.clip(code, 0.0, qmax).astype(jnp.int32)
 
 
+@functools.partial(jax.jit, static_argnums=(1, 2))
 def meta_scale(rng: jnp.ndarray, qmax: float, meta_dtype) -> jnp.ndarray:
     """``max(rng / qmax, EPS)`` in ``meta_dtype`` (f32 quotient, then
     round to nearest even), the same on every backend: for bf16 the

@@ -106,17 +106,17 @@ def _fill_values(out: np.ndarray, spec: ParamSpec, seed: Tuple[int, ...],
         return
     if spec.tp_dim is not None or spec.moe_fold is not None:
         seed = seed + (rank,)
-    np.random.default_rng(seed).random(dtype=np.float32, out=v)
+    gen = np.random.default_rng(seed)
     if spec.init == "lru_lambda":
         # RG-LRU: a = exp(-c*softplus(L)*r); init recurrence ~U(0.9, 0.999)
+        gen.random(dtype=np.float32, out=v)
         u = v * np.float32(0.099) + np.float32(0.9)
         v[:] = np.log(np.exp(-np.log(u) / 8.0) - 1.0)  # inv softplus
         return
-    # fan-in scaled: uniform with std 1/sqrt(fan_in), drawn in place
+    # fan-in normal, drawn in place
     fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
-    half = np.float32(math.sqrt(3.0 / max(fan_in, 1)))
-    v *= 2 * half
-    v -= half
+    gen.standard_normal(dtype=np.float32, out=v)
+    v *= np.float32(1.0 / math.sqrt(max(fan_in, 1)))
 
 
 # --------------------------------------------------------------------------

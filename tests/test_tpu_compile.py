@@ -41,14 +41,17 @@ ROWS, N = 2048, 5120
 def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    # a compile for a described chip cannot be read back without one
+    # a compile for a described chip cannot be read back without one;
+    # the cache setting is restored for the tests that follow
+    was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        return topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:              # noqa: BLE001
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield topologies.get_topology_desc(platform="tpu",
+                                           topology_name="v5e:2x2")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
 
 
 @pytest.fixture(scope="module")
