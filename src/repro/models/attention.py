@@ -186,83 +186,84 @@ def self_attention(p: Dict, x: jnp.ndarray, positions: jnp.ndarray,
 
     x: (B, S, d); positions (S,) for full-seq, scalar pos for decode.
     """
-    valid, kvmap = _head_maps(cfg, plan)
+    with jax.named_scope("attention"):
+        valid, kvmap = _head_maps(cfg, plan)
 
-    if cache is None:
+        if cache is None:
+            q, k, v = _project_qkv(p, x, x, cfg, plan, prefix)
+            if cfg.rope_theta is not None:
+                q = rope(q, positions, cfg.rope_theta)
+                k = rope(k, positions, cfg.rope_theta)
+            ke = jnp.take(k, kvmap, axis=2)       # expand to per-q-head
+            ve = jnp.take(v, kvmap, axis=2)
+            ctx = blockwise_attention(q, ke, ve, positions, positions,
+                                      causal, window)
+            return _finish(p, ctx, valid, policy, cfg, prefix, layer), None
+
+        # ---- cached decode: x is (B, 1, d), positions is scalar ----
+        pos = cache["pos"]
         q, k, v = _project_qkv(p, x, x, cfg, plan, prefix)
         if cfg.rope_theta is not None:
-            q = rope(q, positions, cfg.rope_theta)
-            k = rope(k, positions, cfg.rope_theta)
-        ke = jnp.take(k, kvmap, axis=2)       # expand to per-q-head
-        ve = jnp.take(v, kvmap, axis=2)
-        ctx = blockwise_attention(q, ke, ve, positions, positions,
-                                  causal, window)
-        return _finish(p, ctx, valid, policy, cfg, prefix, layer), None
+            pvec = pos[None].astype(jnp.int32)
+            q = rope(q, pvec, cfg.rope_theta)
+            k = rope(k, pvec, cfg.rope_theta)
+        c_loc = cache["k"].shape[1]
 
-    # ---- cached decode: x is (B, 1, d), positions is scalar ----
-    pos = cache["pos"]
-    q, k, v = _project_qkv(p, x, x, cfg, plan, prefix)
-    if cfg.rope_theta is not None:
-        pvec = pos[None].astype(jnp.int32)
-        q = rope(q, pvec, cfg.rope_theta)
-        k = rope(k, pvec, cfg.rope_theta)
-    c_loc = cache["k"].shape[1]
+        if plan.kv_mode == "shard":
+            # head-sharded cache: every rank holds all positions
+            slot = pos % c_loc
+            ck = lax.dynamic_update_slice(
+                cache["k"], k.astype(cache["k"].dtype), (0, slot, 0, 0))
+            cv = lax.dynamic_update_slice(
+                cache["v"], v.astype(cache["v"].dtype), (0, slot, 0, 0))
+            spos = cache["slot_pos"].at[slot].set(pos)
+        else:
+            # sequence-sharded ring: rank slot//c_loc owns this position
+            slot = pos % (c_loc * plan.tp)
+            owner = slot // c_loc
+            lslot = slot % c_loc
+            rank = lax.axis_index("model")
+            mine = rank == owner
+            ck = lax.dynamic_update_slice(
+                cache["k"], k.astype(cache["k"].dtype), (0, lslot, 0, 0))
+            cv = lax.dynamic_update_slice(
+                cache["v"], v.astype(cache["v"].dtype), (0, lslot, 0, 0))
+            ck = jnp.where(mine, ck, cache["k"])
+            cv = jnp.where(mine, cv, cache["v"])
+            spos = jnp.where(mine, cache["slot_pos"].at[lslot].set(pos),
+                             cache["slot_pos"])
+        new_cache = {"k": ck, "v": cv, "slot_pos": spos, "pos": pos + 1}
 
-    if plan.kv_mode == "shard":
-        # head-sharded cache: every rank holds all positions
-        slot = pos % c_loc
-        ck = lax.dynamic_update_slice(
-            cache["k"], k.astype(cache["k"].dtype), (0, slot, 0, 0))
-        cv = lax.dynamic_update_slice(
-            cache["v"], v.astype(cache["v"].dtype), (0, slot, 0, 0))
-        spos = cache["slot_pos"].at[slot].set(pos)
-    else:
-        # sequence-sharded ring: rank slot//c_loc owns this position
-        slot = pos % (c_loc * plan.tp)
-        owner = slot // c_loc
-        lslot = slot % c_loc
-        rank = lax.axis_index("model")
-        mine = rank == owner
-        ck = lax.dynamic_update_slice(
-            cache["k"], k.astype(cache["k"].dtype), (0, lslot, 0, 0))
-        cv = lax.dynamic_update_slice(
-            cache["v"], v.astype(cache["v"].dtype), (0, lslot, 0, 0))
-        ck = jnp.where(mine, ck, cache["k"])
-        cv = jnp.where(mine, cv, cache["v"])
-        spos = jnp.where(mine, cache["slot_pos"].at[lslot].set(pos),
-                         cache["slot_pos"])
-    new_cache = {"k": ck, "v": cv, "slot_pos": spos, "pos": pos + 1}
+        ke = jnp.take(ck, kvmap, axis=2)          # (B, C_loc, hq_loc, hd)
+        ve = jnp.take(cv, kvmap, axis=2)
+        scale = 1.0 / jnp.sqrt(float(cfg.hd))
+        sc = jnp.einsum("bshd,bchd->bshc", q.astype(jnp.float32),
+                        ke.astype(jnp.float32)) * scale   # (B,1,H,C_loc)
+        mask = (spos >= 0) & (spos <= pos)
+        if causal and window is not None:
+            mask = mask & (spos > pos - window)
+        sc = jnp.where(mask[None, None, None, :], sc, _NEG)
 
-    ke = jnp.take(ck, kvmap, axis=2)          # (B, C_loc, hq_loc, hd)
-    ve = jnp.take(cv, kvmap, axis=2)
-    scale = 1.0 / jnp.sqrt(float(cfg.hd))
-    sc = jnp.einsum("bshd,bchd->bshc", q.astype(jnp.float32),
-                    ke.astype(jnp.float32)) * scale   # (B,1,H,C_loc)
-    mask = (spos >= 0) & (spos <= pos)
-    if causal and window is not None:
-        mask = mask & (spos > pos - window)
-    sc = jnp.where(mask[None, None, None, :], sc, _NEG)
-
-    if plan.kv_mode == "shard":
-        w = jax.nn.softmax(sc, axis=-1)
-        ctx = jnp.einsum("bshc,bchd->bshd", w, ve.astype(jnp.float32))
-    else:
-        # per-rank online-softmax partials, merged with a tiny stats
-        # all-gather over the model axis (B*H*(hd+2) floats per rank)
-        m_loc = jnp.max(sc, axis=-1)                       # (B,1,H)
-        pw = jnp.exp(sc - m_loc[..., None])
-        l_loc = jnp.sum(pw, axis=-1)
-        acc = jnp.einsum("bshc,bchd->bshd", pw, ve.astype(jnp.float32))
-        m_all = lax.all_gather(m_loc, "model", axis=0)     # (tp,B,1,H)
-        l_all = lax.all_gather(l_loc, "model", axis=0)
-        a_all = lax.all_gather(acc, "model", axis=0)
-        m_g = jnp.max(m_all, axis=0)
-        corr = jnp.exp(m_all - m_g[None])
-        l_g = jnp.sum(l_all * corr, axis=0)
-        ctx = (jnp.sum(a_all * corr[..., None], axis=0)
-               / jnp.maximum(l_g, 1e-20)[..., None])
-    ctx = ctx.astype(x.dtype)
-    return _finish(p, ctx, valid, policy, cfg, prefix, layer), new_cache
+        if plan.kv_mode == "shard":
+            w = jax.nn.softmax(sc, axis=-1)
+            ctx = jnp.einsum("bshc,bchd->bshd", w, ve.astype(jnp.float32))
+        else:
+            # per-rank online-softmax partials, merged with a tiny stats
+            # all-gather over the model axis (B*H*(hd+2) floats per rank)
+            m_loc = jnp.max(sc, axis=-1)                       # (B,1,H)
+            pw = jnp.exp(sc - m_loc[..., None])
+            l_loc = jnp.sum(pw, axis=-1)
+            acc = jnp.einsum("bshc,bchd->bshd", pw, ve.astype(jnp.float32))
+            m_all = lax.all_gather(m_loc, "model", axis=0)     # (tp,B,1,H)
+            l_all = lax.all_gather(l_loc, "model", axis=0)
+            a_all = lax.all_gather(acc, "model", axis=0)
+            m_g = jnp.max(m_all, axis=0)
+            corr = jnp.exp(m_all - m_g[None])
+            l_g = jnp.sum(l_all * corr, axis=0)
+            ctx = (jnp.sum(a_all * corr[..., None], axis=0)
+                   / jnp.maximum(l_g, 1e-20)[..., None])
+        ctx = ctx.astype(x.dtype)
+        return _finish(p, ctx, valid, policy, cfg, prefix, layer), new_cache
 
 
 def cross_attention(p: Dict, x: jnp.ndarray, enc: jnp.ndarray,
@@ -272,13 +273,14 @@ def cross_attention(p: Dict, x: jnp.ndarray, enc: jnp.ndarray,
     """Cross-attention onto encoder/image embeddings (B, Senc, d).
     No positional rotation on q/k (whisper/mllama style abs-pos is in the
     embeddings); never causal; no cache needed (enc is static)."""
-    valid, kvmap = _head_maps(cfg, plan)
-    q, k, v = _project_qkv(p, x, enc, cfg, plan, prefix)
-    senc = enc.shape[1]
-    kpos = jnp.arange(senc)
-    qpos = jnp.zeros((x.shape[1],), jnp.int32)
-    ke = jnp.take(k, kvmap, axis=2)
-    ve = jnp.take(v, kvmap, axis=2)
-    ctx = blockwise_attention(q, ke, ve, qpos, kpos, causal=False,
-                              window=None)
-    return _finish(p, ctx, valid, policy, cfg, prefix, layer)
+    with jax.named_scope("attention"):
+        valid, kvmap = _head_maps(cfg, plan)
+        q, k, v = _project_qkv(p, x, enc, cfg, plan, prefix)
+        senc = enc.shape[1]
+        kpos = jnp.arange(senc)
+        qpos = jnp.zeros((x.shape[1],), jnp.int32)
+        ke = jnp.take(k, kvmap, axis=2)
+        ve = jnp.take(v, kvmap, axis=2)
+        ctx = blockwise_attention(q, ke, ve, qpos, kpos, causal=False,
+                                  window=None)
+        return _finish(p, ctx, valid, policy, cfg, prefix, layer)

@@ -30,7 +30,8 @@ def tp_psum(x: jnp.ndarray, policy: CommPolicy, groups=None,
     """
     cfg = policy.resolve("tp", layer) or NO_COMPRESSION
     bwd = policy.resolve("tp_bwd", layer)
-    return compressed_psum(x, TP_AXES, cfg, groups, bwd)
+    with jax.named_scope("tp_site"):
+        return compressed_psum(x, TP_AXES, cfg, groups, bwd)
 
 
 # --------------------------------------------------------------------------
@@ -149,19 +150,20 @@ def vocab_parallel_ce(logits_loc: jnp.ndarray, labels: jnp.ndarray,
 def mlp_apply(p: Dict, x: jnp.ndarray, act: str, policy: CommPolicy,
               use_bias: bool = False,
               layer: Optional[int] = None) -> jnp.ndarray:
-    if act in ("swiglu", "geglu"):
-        h = jnp.einsum("...d,df->...f", x, p["w1"])
-        g = jnp.einsum("...d,df->...f", x, p["w3"])
+    with jax.named_scope("mlp"):
+        if act in ("swiglu", "geglu"):
+            h = jnp.einsum("...d,df->...f", x, p["w1"])
+            g = jnp.einsum("...d,df->...f", x, p["w3"])
+            if use_bias:
+                h, g = h + p["b1"], g + p["b3"]
+            h = (jax.nn.silu(h) if act == "swiglu" else gelu(h)) * g
+        else:
+            h = jnp.einsum("...d,df->...f", x, p["w1"])
+            if use_bias:
+                h = h + p["b1"]
+            h = gelu(h)
+        y = jnp.einsum("...f,fd->...d", h, p["w2"])
+        y = tp_psum(y, policy, layer=layer)
         if use_bias:
-            h, g = h + p["b1"], g + p["b3"]
-        h = (jax.nn.silu(h) if act == "swiglu" else gelu(h)) * g
-    else:
-        h = jnp.einsum("...d,df->...f", x, p["w1"])
-        if use_bias:
-            h = h + p["b1"]
-        h = gelu(h)
-    y = jnp.einsum("...f,fd->...d", h, p["w2"])
-    y = tp_psum(y, policy, layer=layer)
-    if use_bias:
-        y = y + p["b2"]
-    return y.astype(x.dtype)
+            y = y + p["b2"]
+        return y.astype(x.dtype)

@@ -310,122 +310,126 @@ def forward(views: Dict, tokens: jnp.ndarray, cfg: ModelConfig,
     decode = caches is not None
     has_deltas = grad_deltas is not None
 
-    emb_specs = groups["embed"][1]
-    pe = gather_group({k: v[0] for k, v in views["embed"].items()},
-                      emb_specs, plan, dtype, qag,
-                      _take0(grad_deltas["embed"] if has_deltas else None))
-    x = embed_lookup(tokens, pe["tok"], policy, dtype)
+    with jax.named_scope("embed"):
+        emb_specs = groups["embed"][1]
+        pe = gather_group({k: v[0] for k, v in views["embed"].items()},
+                          emb_specs, plan, dtype, qag,
+                          _take0(grad_deltas["embed"] if has_deltas else None))
+        x = embed_lookup(tokens, pe["tok"], policy, dtype)
 
-    if decode:
-        # every attn cache holds the same position counter; take the first
-        pos_ref = _first_pos(caches)
-        positions = pos_ref
-    else:
-        positions = jnp.arange(tokens.shape[1])
-    if cfg.rope_theta is None and cfg.learned_pos:
         if decode:
-            pos_id = jnp.clip(positions, 0, cfg.max_pos - 1)
-            x = x + jnp.take(pe["pos"], pos_id[None].astype(jnp.int32),
-                             axis=0).astype(dtype)
+            # every attn cache holds the same position counter; take the first
+            pos_ref = _first_pos(caches)
+            positions = pos_ref
         else:
-            x = x + pe["pos"][None, :tokens.shape[1]].astype(dtype)
+            positions = jnp.arange(tokens.shape[1])
+        if cfg.rope_theta is None and cfg.learned_pos:
+            if decode:
+                pos_id = jnp.clip(positions, 0, cfg.max_pos - 1)
+                x = x + jnp.take(pe["pos"], pos_id[None].astype(jnp.int32),
+                                 axis=0).astype(dtype)
+            else:
+                x = x + pe["pos"][None, :tokens.shape[1]].astype(dtype)
 
-    enc_out = None
-    if cfg.is_enc_dec:
-        assert enc_embeds is not None
-        enc_out = _encode(views, cfg, plan, policy,
-                          enc_embeds.astype(dtype), qag, grad_deltas)
-    elif cfg.has_cross:
-        assert enc_embeds is not None
-        enc_out = enc_embeds.astype(dtype)
+    with jax.named_scope("layers"):
+        enc_out = None
+        if cfg.is_enc_dec:
+            assert enc_embeds is not None
+            enc_out = _encode(views, cfg, plan, policy,
+                              enc_embeds.astype(dtype), qag, grad_deltas)
+        elif cfg.has_cross:
+            assert enc_embeds is not None
+            enc_out = enc_embeds.astype(dtype)
 
-    aux_total = jnp.zeros((), jnp.float32)
-    new_caches: Dict[str, Any] = {}
+        aux_total = jnp.zeros((), jnp.float32)
+        new_caches: Dict[str, Any] = {}
 
-    def run_one(kind, gname, layer, carry_x, cache):
-        specs = groups[gname][1]
-        p = gather_group({k: v[0] for k, v in views[gname].items()},
-                         specs, plan, dtype, qag,
-                         _take0(grad_deltas[gname] if has_deltas
-                                else None))
-        return apply_block(kind, p, carry_x, positions=positions,
-                           enc_out=enc_out, cfg=cfg, plan=plan,
-                           policy=policy, window_override=window_override,
-                           cache=cache, layer=layer)
+        def run_one(kind, gname, layer, carry_x, cache):
+            specs = groups[gname][1]
+            p = gather_group({k: v[0] for k, v in views[gname].items()},
+                             specs, plan, dtype, qag,
+                             _take0(grad_deltas[gname] if has_deltas
+                                    else None))
+            return apply_block(kind, p, carry_x, positions=positions,
+                               enc_out=enc_out, cfg=cfg, plan=plan,
+                               policy=policy, window_override=window_override,
+                               cache=cache, layer=layer)
 
-    for i, kind in enumerate(cfg.prefix):
-        g = f"pre{i}_{kind}"
-        x, nc, aux = jax.checkpoint(
-            functools.partial(run_one, kind, g, i))(
-                x, caches.get(g) if decode else None)
-        aux_total += aux
-        if decode:
-            new_caches[g] = nc
+        for i, kind in enumerate(cfg.prefix):
+            g = f"pre{i}_{kind}"
+            x, nc, aux = jax.checkpoint(
+                functools.partial(run_one, kind, g, i))(
+                    x, caches.get(g) if decode else None)
+            aux_total += aux
+            if decode:
+                new_caches[g] = nc
 
-    if cfg.pattern_repeats:
-        specs = groups["pattern"][1]
-        base, period = len(cfg.prefix), len(cfg.pattern)
+        if cfg.pattern_repeats:
+            specs = groups["pattern"][1]
+            base, period = len(cfg.prefix), len(cfg.pattern)
 
-        def make_body(layer0):
-            # layer0: first global block index of the segment; the
-            # resolved configs are constant across the segment's
-            # repeats, so resolving at layer0 + j binds the right
-            # config for every repeat the scan covers.
-            def body(carry, xs):
-                cx, caux = carry
-                layer_views, layer_deltas, layer_cache = xs
-                p = gather_group(layer_views, specs, plan, dtype, qag,
-                                 layer_deltas if has_deltas else None)
-                ncs = {}
-                for j, kind in enumerate(cfg.pattern):
-                    pj = {n[len(f"L{j}_"):]: v for n, v in p.items()
-                          if n.startswith(f"L{j}_")}
-                    cj = layer_cache.get(f"L{j}") if decode else None
-                    cx, nc, aux = apply_block(
-                        kind, pj, cx, positions=positions, enc_out=enc_out,
-                        cfg=cfg, plan=plan, policy=policy,
-                        window_override=window_override, cache=cj,
-                        layer=layer0 + j)
-                    caux += aux
-                    ncs[f"L{j}"] = nc
-                return (cx, caux), ncs
-            return body
+            def make_body(layer0):
+                # layer0: first global block index of the segment; the
+                # resolved configs are constant across the segment's
+                # repeats, so resolving at layer0 + j binds the right
+                # config for every repeat the scan covers.
+                def body(carry, xs):
+                    cx, caux = carry
+                    layer_views, layer_deltas, layer_cache = xs
+                    p = gather_group(layer_views, specs, plan, dtype, qag,
+                                     layer_deltas if has_deltas else None)
+                    ncs = {}
+                    for j, kind in enumerate(cfg.pattern):
+                        pj = {n[len(f"L{j}_"):]: v for n, v in p.items()
+                              if n.startswith(f"L{j}_")}
+                        cj = layer_cache.get(f"L{j}") if decode else None
+                        cx, nc, aux = apply_block(
+                            kind, pj, cx, positions=positions, enc_out=enc_out,
+                            cfg=cfg, plan=plan, policy=policy,
+                            window_override=window_override, cache=cj,
+                            layer=layer0 + j)
+                        caux += aux
+                        ncs[f"L{j}"] = nc
+                    return (cx, caux), ncs
+                return body
 
-        xs = (views["pattern"],
-              grad_deltas["pattern"] if has_deltas else
-              jnp.zeros((cfg.pattern_repeats,)),
-              caches["pattern"] if decode else
-              jnp.zeros((cfg.pattern_repeats,)))
-        seg_caches = []
-        for s, e in policy_segments(cfg, policy):
-            xs_seg = xs if (s, e) == (0, cfg.pattern_repeats) else \
-                jax.tree_util.tree_map(lambda a: a[s:e], xs)
-            (x, aux_total), pc = lax.scan(
-                jax.checkpoint(make_body(base + s * period)),
-                (x, aux_total), xs_seg,
-                unroll=(e - s) if UNROLL_LAYER_SCAN else 1)
-            seg_caches.append(pc)
-        if decode:
-            new_caches["pattern"] = seg_caches[0] if len(seg_caches) == 1 \
-                else jax.tree_util.tree_map(
-                    lambda *cs: jnp.concatenate(cs, axis=0), *seg_caches)
+            xs = (views["pattern"],
+                  grad_deltas["pattern"] if has_deltas else
+                  jnp.zeros((cfg.pattern_repeats,)),
+                  caches["pattern"] if decode else
+                  jnp.zeros((cfg.pattern_repeats,)))
+            seg_caches = []
+            for s, e in policy_segments(cfg, policy):
+                xs_seg = xs if (s, e) == (0, cfg.pattern_repeats) else \
+                    jax.tree_util.tree_map(lambda a: a[s:e], xs)
+                (x, aux_total), pc = lax.scan(
+                    jax.checkpoint(make_body(base + s * period)),
+                    (x, aux_total), xs_seg,
+                    unroll=(e - s) if UNROLL_LAYER_SCAN else 1)
+                seg_caches.append(pc)
+            if decode:
+                new_caches["pattern"] = seg_caches[0] if len(seg_caches) == 1 \
+                    else jax.tree_util.tree_map(
+                        lambda *cs: jnp.concatenate(cs, axis=0), *seg_caches)
 
-    for i, kind in enumerate(cfg.suffix):
-        g = f"suf{i}_{kind}"
-        layer = len(cfg.prefix) + len(cfg.pattern) * cfg.pattern_repeats + i
-        x, nc, aux = jax.checkpoint(
-            functools.partial(run_one, kind, g, layer))(
-                x, caches.get(g) if decode else None)
-        aux_total += aux
-        if decode:
-            new_caches[g] = nc
+        for i, kind in enumerate(cfg.suffix):
+            g = f"suf{i}_{kind}"
+            layer = (len(cfg.prefix)
+                     + len(cfg.pattern) * cfg.pattern_repeats + i)
+            x, nc, aux = jax.checkpoint(
+                functools.partial(run_one, kind, g, layer))(
+                    x, caches.get(g) if decode else None)
+            aux_total += aux
+            if decode:
+                new_caches[g] = nc
 
-    out_specs = groups["out"][1]
-    po = gather_group({k: v[0] for k, v in views["out"].items()},
-                      out_specs, plan, dtype, qag,
-                      _take0(grad_deltas["out"] if has_deltas else None))
-    x = _norm(po, x, cfg, "nf_")
-    unemb = po["unemb"] if not cfg.tie_embeddings else pe["tok"]
+    with jax.named_scope("head"):
+        out_specs = groups["out"][1]
+        po = gather_group({k: v[0] for k, v in views["out"].items()},
+                          out_specs, plan, dtype, qag,
+                          _take0(grad_deltas["out"] if has_deltas else None))
+        x = _norm(po, x, cfg, "nf_")
+        unemb = po["unemb"] if not cfg.tie_embeddings else pe["tok"]
     return x, unemb, aux_total, (new_caches if decode else None)
 
 

@@ -139,7 +139,8 @@ def moe_apply(p: Dict, x: jnp.ndarray, cfg: ModelConfig,
         h = gelu(h)
     y = jnp.einsum("etf,efd->etd", h, p[prefix + "w2"])
     if mp.etp > 1:
-        y = compressed_psum(y, ("model",), tp_cfg, mp.etp_groups)
+        with jax.named_scope("tp_site"):
+            y = compressed_psum(y, ("model",), tp_cfg, mp.etp_groups)
 
     # ---- combine (BF16, unquantized — paper-faithful) ----
     y = y.reshape(mp.e_loc, mp.ep, cap, d).transpose(1, 0, 2, 3)

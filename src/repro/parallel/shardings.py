@@ -181,10 +181,11 @@ def gather_group(views: Dict[str, jnp.ndarray],
                  qag: Optional[CommConfig] = None,
                  deltas: Optional[Dict[str, jnp.ndarray]] = None
                  ) -> Dict[str, jnp.ndarray]:
-    return {name: gather_param(views[name], specs[name], plan, dtype,
-                               qag,
-                               None if deltas is None else deltas[name])
-            for name in specs}
+    with jax.named_scope("weights"):
+        return {name: gather_param(views[name], specs[name], plan, dtype,
+                                   qag,
+                                   None if deltas is None else deltas[name])
+                for name in specs}
 
 
 # --------------------------------------------------------------------------

@@ -30,13 +30,14 @@ def _head(hidden, unemb, cfg: ModelConfig, plan: ShardingPlan,
           logits: bool):
     """Greedy next token (B,), plus with ``logits`` the last-position
     vocab-parallel logits (B, v_loc); padded vocab columns are -inf."""
-    nt = greedy_next_token(hidden, unemb, cfg, plan)
-    if not logits:
-        return nt
-    lg = vocab_parallel_logits(hidden[:, -1], unemb, cfg.logit_softcap)
-    col = (jnp.arange(plan.v_loc)[None, :]
-           + jax.lax.axis_index("model") * plan.v_loc)
-    return nt, jnp.where(col < cfg.vocab, lg, -jnp.inf)
+    with jax.named_scope("head"):
+        nt = greedy_next_token(hidden, unemb, cfg, plan)
+        if not logits:
+            return nt
+        lg = vocab_parallel_logits(hidden[:, -1], unemb, cfg.logit_softcap)
+        col = (jnp.arange(plan.v_loc)[None, :]
+               + jax.lax.axis_index("model") * plan.v_loc)
+        return nt, jnp.where(col < cfg.vocab, lg, -jnp.inf)
 
 
 def _logit_spec(bspec) -> P:
