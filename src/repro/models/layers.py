@@ -92,15 +92,36 @@ def rope(x: jnp.ndarray, positions: jnp.ndarray,
 # vocab-parallel embedding + cross-entropy
 # --------------------------------------------------------------------------
 
+def _local_ids(tokens: jnp.ndarray, v_loc: int):
+    """Global ids -> (row in this rank's vocab slice, clipped; whether
+    the id is in the slice)."""
+    rank = lax.axis_index("model")
+    ids = tokens - rank * v_loc
+    ok = (ids >= 0) & (ids < v_loc)
+    return jnp.clip(ids, 0, v_loc - 1), ok
+
+
 def embed_lookup(tokens: jnp.ndarray, emb_loc: jnp.ndarray,
                  policy: CommPolicy, dtype) -> jnp.ndarray:
     """tokens (B,S) int32; emb_loc (v_loc, d) = this rank's vocab rows.
     Masked local lookup + TP psum (the paper's quantized AR site)."""
-    v_loc = emb_loc.shape[0]
-    rank = lax.axis_index("model")
-    ids = tokens - rank * v_loc
-    ok = (ids >= 0) & (ids < v_loc)
-    vec = jnp.take(emb_loc, jnp.clip(ids, 0, v_loc - 1), axis=0)
+    rows, ok = _local_ids(tokens, emb_loc.shape[0])
+    vec = jnp.take(emb_loc, rows, axis=0)
+    vec = jnp.where(ok[..., None], vec, 0).astype(dtype)
+    return tp_psum(vec, policy).astype(dtype)
+
+
+def embed_rows(tokens: jnp.ndarray, flat: jnp.ndarray, v_loc: int, d: int,
+               policy: CommPolicy, dtype) -> jnp.ndarray:
+    """:func:`embed_lookup` read from this rank's flat store row ``flat``
+    (its ``(v_loc, d)`` table flattened, any dtype): only the tokens'
+    ``d``-wide rows are gathered, then cast. Casting commutes with the
+    gather, so the result is embed_lookup's bit for bit."""
+    if v_loc * d >= 2 ** 31:
+        raise ValueError(f"a {v_loc} x {d} table is past int32 offsets")
+    rows, ok = _local_ids(tokens, v_loc)
+    vec = jax.vmap(lambda r: lax.dynamic_slice(flat, (r * d,), (d,)))(
+        rows.reshape(-1)).reshape(*rows.shape, d).astype(dtype)
     vec = jnp.where(ok[..., None], vec, 0).astype(dtype)
     return tp_psum(vec, policy).astype(dtype)
 

@@ -25,10 +25,11 @@ from repro.models import attention as attn
 from repro.models import moe as moe_mod
 from repro.models import recurrent as rec_mod
 from repro.models.config import ModelConfig
-from repro.models.layers import (apply_norm, embed_lookup, mlp_apply,
-                                 vocab_parallel_ce, vocab_parallel_logits)
+from repro.models.layers import (apply_norm, embed_lookup, embed_rows,
+                                 mlp_apply, vocab_parallel_ce,
+                                 vocab_parallel_logits)
 from repro.parallel.plan import ShardingPlan, make_plan
-from repro.parallel.shardings import ParamSpec, gather_group
+from repro.parallel.shardings import ParamSpec, gather_group, serve_group
 
 # Roofline builds set this so the pattern/encoder scans fully unroll and
 # XLA's cost_analysis (which counts while bodies once) sees every layer.
@@ -253,29 +254,38 @@ def _take0(tree):
     return None if tree is None else {k: v[0] for k, v in tree.items()}
 
 
-def _encode(views, cfg, plan, policy, enc_embeds, qag, deltas=None):
+def _encode(views, cfg, plan, policy, enc_embeds, qag, deltas=None,
+            serve=False):
     """Whisper-style encoder over stub frame embeddings (B, n_ctx, d)."""
     has_deltas = deltas is not None
+    dtype = enc_embeds.dtype
     gx = views["encoder_extra"]
     specs_x = param_groups(cfg, plan)["encoder_extra"][1]
-    px = gather_group({k: v[0] for k, v in gx.items()}, specs_x, plan,
-                      enc_embeds.dtype, qag,
-                      _take0(deltas["encoder_extra"] if has_deltas
-                             else None))
+    if serve:
+        px = serve_group(gx, specs_x, plan, dtype)
+    else:
+        px = gather_group({k: v[0] for k, v in gx.items()}, specs_x, plan,
+                          dtype, qag,
+                          _take0(deltas["encoder_extra"] if has_deltas
+                                 else None))
     x = enc_embeds + px["enc_pos"][None, :enc_embeds.shape[1]]
     specs = param_groups(cfg, plan)["encoder"][1]
     pos = jnp.arange(enc_embeds.shape[1])
 
     def body(carry, xs):
         layer_views, layer_deltas = xs
-        p = gather_group(layer_views, specs, plan, enc_embeds.dtype, qag,
-                         layer_deltas if has_deltas else None)
+        if serve:    # layer_views is the layer index into the stack
+            p = serve_group(views["encoder"], specs, plan, dtype,
+                            layer_views)
+        else:
+            p = gather_group(layer_views, specs, plan, dtype, qag,
+                             layer_deltas if has_deltas else None)
         y, _, _ = apply_block("enc", p, carry, positions=pos, enc_out=None,
                               cfg=cfg, plan=plan, policy=policy,
                               window_override=None, cache=None)
         return y, None
 
-    xs = (views["encoder"],
+    xs = (jnp.arange(cfg.encoder.n_layers) if serve else views["encoder"],
           deltas["encoder"] if has_deltas
           else jnp.zeros((cfg.encoder.n_layers,)))
     x, _ = lax.scan(jax.checkpoint(body), x, xs,
@@ -290,11 +300,18 @@ def forward(views: Dict, tokens: jnp.ndarray, cfg: ModelConfig,
             window_override: Optional[int] = None,
             caches: Optional[Dict] = None,
             grad_deltas: Optional[Dict] = None,
-            dtype=jnp.bfloat16):
+            dtype=jnp.bfloat16, serving: bool = False):
     """tokens (B_loc, S) -> (hidden (B_loc,S,d), aux, new_caches).
 
     caches=None -> full-sequence (train/prefill). caches given -> S must
     be 1 (single-token decode step).
+
+    ``serving`` is passed by the serving entry points alone
+    (``train/serve_step.py``). With resident weights (``fsdp == 1``) they
+    read the store through ``shardings.serve_group``, which has no VJP:
+    each tensor is read once, and an untied embedding gathers only the
+    tokens' rows (``layers.embed_rows``). Training, and serving with
+    ``fsdp > 1``, take ``gather_group``.
 
     ``grad_deltas`` (train-only) mirrors ``views``' nesting with zero
     full-flat-length leaves; when given, every gathered parameter is
@@ -309,13 +326,28 @@ def forward(views: Dict, tokens: jnp.ndarray, cfg: ModelConfig,
     qag = policy.resolve("qag")
     decode = caches is not None
     has_deltas = grad_deltas is not None
+    serve = serving and plan.fsdp == 1
+
+    def weights(gname, specs=None):
+        """An unstacked group's logical weights."""
+        specs = groups[gname][1] if specs is None else specs
+        if serve:
+            return serve_group(views[gname], specs, plan, dtype)
+        return gather_group({k: v[0] for k, v in views[gname].items()},
+                            specs, plan, dtype, qag,
+                            _take0(grad_deltas[gname] if has_deltas
+                                   else None))
 
     with jax.named_scope("embed"):
         emb_specs = groups["embed"][1]
-        pe = gather_group({k: v[0] for k, v in views["embed"].items()},
-                          emb_specs, plan, dtype, qag,
-                          _take0(grad_deltas["embed"] if has_deltas else None))
-        x = embed_lookup(tokens, pe["tok"], policy, dtype)
+        rows = serve and not cfg.tie_embeddings
+        pe = weights("embed", {k: sp for k, sp in emb_specs.items()
+                               if not (rows and k == "tok")})
+        if rows:
+            x = embed_rows(tokens, views["embed"]["tok"][0, 0], plan.v_loc,
+                           cfg.d_model, policy, dtype)
+        else:
+            x = embed_lookup(tokens, pe["tok"], policy, dtype)
 
         if decode:
             # every attn cache holds the same position counter; take the first
@@ -336,7 +368,8 @@ def forward(views: Dict, tokens: jnp.ndarray, cfg: ModelConfig,
         if cfg.is_enc_dec:
             assert enc_embeds is not None
             enc_out = _encode(views, cfg, plan, policy,
-                              enc_embeds.astype(dtype), qag, grad_deltas)
+                              enc_embeds.astype(dtype), qag, grad_deltas,
+                              serve)
         elif cfg.has_cross:
             assert enc_embeds is not None
             enc_out = enc_embeds.astype(dtype)
@@ -345,11 +378,7 @@ def forward(views: Dict, tokens: jnp.ndarray, cfg: ModelConfig,
         new_caches: Dict[str, Any] = {}
 
         def run_one(kind, gname, layer, carry_x, cache):
-            specs = groups[gname][1]
-            p = gather_group({k: v[0] for k, v in views[gname].items()},
-                             specs, plan, dtype, qag,
-                             _take0(grad_deltas[gname] if has_deltas
-                                    else None))
+            p = weights(gname)
             return apply_block(kind, p, carry_x, positions=positions,
                                enc_out=enc_out, cfg=cfg, plan=plan,
                                policy=policy, window_override=window_override,
@@ -376,8 +405,13 @@ def forward(views: Dict, tokens: jnp.ndarray, cfg: ModelConfig,
                 def body(carry, xs):
                     cx, caux = carry
                     layer_views, layer_deltas, layer_cache = xs
-                    p = gather_group(layer_views, specs, plan, dtype, qag,
-                                     layer_deltas if has_deltas else None)
+                    if serve:    # layer_views is the layer index
+                        p = serve_group(views["pattern"], specs, plan,
+                                        dtype, layer_views)
+                    else:
+                        p = gather_group(layer_views, specs, plan, dtype,
+                                         qag, layer_deltas if has_deltas
+                                         else None)
                     ncs = {}
                     for j, kind in enumerate(cfg.pattern):
                         pj = {n[len(f"L{j}_"):]: v for n, v in p.items()
@@ -393,7 +427,8 @@ def forward(views: Dict, tokens: jnp.ndarray, cfg: ModelConfig,
                     return (cx, caux), ncs
                 return body
 
-            xs = (views["pattern"],
+            xs = (jnp.arange(cfg.pattern_repeats) if serve
+                  else views["pattern"],
                   grad_deltas["pattern"] if has_deltas else
                   jnp.zeros((cfg.pattern_repeats,)),
                   caches["pattern"] if decode else
@@ -424,10 +459,7 @@ def forward(views: Dict, tokens: jnp.ndarray, cfg: ModelConfig,
                 new_caches[g] = nc
 
     with jax.named_scope("head"):
-        out_specs = groups["out"][1]
-        po = gather_group({k: v[0] for k, v in views["out"].items()},
-                          out_specs, plan, dtype, qag,
-                          _take0(grad_deltas["out"] if has_deltas else None))
+        po = weights("out")
         x = _norm(po, x, cfg, "nf_")
         unemb = po["unemb"] if not cfg.tie_embeddings else pe["tok"]
     return x, unemb, aux_total, (new_caches if decode else None)

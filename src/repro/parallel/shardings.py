@@ -25,6 +25,10 @@ the deltas hands the train step per-rank *full* gradients, and the
 quantized+EF reduce-scatter runs as an explicit post-``value_and_grad``
 pass (``train_step.py`` -> ``collectives.quantized_reduce_scatter_ef``)
 with its residual pytree in optimizer state.
+
+Serving with resident weights (``fsdp == 1``) needs no gradient and
+reads the store through ``serve_param`` instead: each tensor once, the
+matrices on the TPU through the ``unflatten_cast`` kernel.
 """
 from __future__ import annotations
 
@@ -42,6 +46,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import codec
 from repro.core.comm_config import CommConfig
+from repro.kernels.unflatten_cast import fits, unflatten_cast
 from repro.parallel.plan import ShardingPlan, flat_store_len
 
 STORE_SPEC = P(None, "model", "data")
@@ -158,6 +163,11 @@ def gather_param(flat_view: jnp.ndarray, spec: ParamSpec,
                  delta: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Per-rank storage view (1, flat/fsdp) -> logical local array.
 
+    The differentiable path, which training takes (and serving with
+    ``fsdp > 1``): on the TPU the final ``reshape(shape).astype(dtype)``
+    compiles to two passes over HBM, a cast to a padded 1-D array and a
+    relayout. Serving with resident weights takes :func:`serve_param`.
+
     ``delta`` (a zero full-length ``(1, flat)`` per-rank array) is the
     gradient tap for the out-of-VJP qgrad path: when given, the gathered
     weights are stop-gradiented and ``delta`` added, so the grad w.r.t.
@@ -185,6 +195,43 @@ def gather_group(views: Dict[str, jnp.ndarray],
         return {name: gather_param(views[name], specs[name], plan, dtype,
                                    qag,
                                    None if deltas is None else deltas[name])
+                for name in specs}
+
+
+# --------------------------------------------------------------------------
+# serving read (resident weights, no gradient)
+# --------------------------------------------------------------------------
+
+def _backend() -> str:
+    return jax.default_backend()
+
+
+def serve_param(stack: jnp.ndarray, spec: ParamSpec, plan: ShardingPlan,
+                dtype, layer=0) -> jnp.ndarray:
+    """Per-rank stacked store ``(n_stack, 1, flat)`` -> layer ``layer``'s
+    logical local array, for serving with ``fsdp == 1``.
+
+    On the TPU a tensor the :mod:`~repro.kernels.unflatten_cast` kernel
+    fits (two or more dimensions, the last a multiple of 128, at least
+    one 4 MiB block of float32) is read once and written once, straight
+    from the stack: inside a layer scan ``layer`` is the traced layer
+    index, so no per-layer slice is copied and the compiler has no cast
+    of the whole stack to hoist out of the loop. Everything else, and every tensor off the TPU, takes
+    ``gather_param``'s expression. Both equal it bit for bit. No VJP.
+    """
+    shape = spec.local_shape(plan)
+    if _backend() == "tpu" and fits(shape):
+        return unflatten_cast(stack, layer, shape=shape, dtype=dtype)
+    row = lax.dynamic_index_in_dim(stack, layer, 0, keepdims=False)
+    return row.reshape(-1)[:math.prod(shape)].reshape(shape).astype(dtype)
+
+
+def serve_group(stacks: Dict[str, jnp.ndarray],
+                specs: Dict[str, ParamSpec], plan: ShardingPlan, dtype,
+                layer=0) -> Dict[str, jnp.ndarray]:
+    with jax.named_scope("weights"):
+        return {name: serve_param(stacks[name], specs[name], plan, dtype,
+                                  layer)
                 for name in specs}
 
 

@@ -60,7 +60,7 @@ def make_prefill(cfg: ModelConfig, plan: ShardingPlan, policy: CommPolicy,
         hidden, unemb, _, _ = forward(
             store, batch["tokens"], cfg, plan, policy,
             enc_embeds=batch.get("enc_embeds"),
-            window_override=window_override, dtype=dtype)
+            window_override=window_override, dtype=dtype, serving=True)
         return _head(hidden, unemb, cfg, plan, logits)
 
     bs = {"tokens": bspec}
@@ -178,7 +178,7 @@ def make_decode_step(cfg: ModelConfig, plan: ShardingPlan,
         hidden, unemb, _, new_caches = forward(
             store, batch["tokens"], cfg, plan, policy,
             enc_embeds=batch.get("enc_embeds"), caches=caches,
-            window_override=window_override, dtype=dtype)
+            window_override=window_override, dtype=dtype, serving=True)
         return _head(hidden, unemb, cfg, plan, logits), new_caches
 
     bs = {"tokens": bspec}

@@ -573,7 +573,43 @@ def check_depth_policy_train():
     print("depth_policy_train ok", losses)
 
 
+def check_embed_rows():
+    """TP=4: the row-gather embedding from each rank's flat float32 table
+    equals the lookup in its cast table, for ids in every rank's slice
+    and in the padded vocabulary (1001 ids, padded to 1004)."""
+    from repro.core.policy import BF16_POLICY
+    from repro.models.layers import embed_lookup, embed_rows
+
+    mesh = make_test_mesh(data=1, model=4)
+    vocab, v_pad, d = 1001, 1004, 256
+    v_loc = v_pad // 4
+    table = np.zeros((v_pad, d), np.float32)
+    table[:vocab] = np.random.default_rng(7).standard_normal((vocab, d)) * 3
+    flat = np.zeros((4, v_loc * d + 512), np.float32)
+    for r in range(4):
+        flat[r, :v_loc * d] = table[r * v_loc:(r + 1) * v_loc].reshape(-1)
+    ids = [0, 250, 251, 502, 503, 753, 754, 1000, 1001, 1003, 17, 600]
+    toks = jnp.asarray(np.array(ids, np.int32).reshape(2, 6))
+
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P("model"), P()),
+             out_specs=(P(), P()), check_vma=False)
+    def f(flat_r, tokens):
+        row = flat_r[0]
+        cast = row[:v_loc * d].reshape(v_loc, d).astype(jnp.bfloat16)
+        return (embed_rows(tokens, row, v_loc, d, BF16_POLICY,
+                           jnp.bfloat16),
+                embed_lookup(tokens, cast, BF16_POLICY, jnp.bfloat16))
+
+    got, want = jax.jit(f)(jnp.asarray(flat), toks)
+    got = np.asarray(got).view(np.uint16)
+    assert np.array_equal(got, np.asarray(want).view(np.uint16))
+    host = table.astype(jnp.bfloat16)[np.array(ids)].reshape(2, 6, d)
+    assert np.array_equal(got, host.view(np.uint16))
+    print("embed_rows ok")
+
+
 CHECKS = {
+    "embed_rows": check_embed_rows,
     "quantized_ar": check_quantized_ar,
     "fused_ar": check_fused_ar,
     "framed_bridge": check_framed_bridge,

@@ -7,13 +7,18 @@ These tests compile the real kernels at real widths for a ``v5e:2x2``
 that is described, not attached: the wire encode/decode at the four
 codec configs of the benchmark plan, a flattened all-reduce message
 longer than one grid step takes, and the fused RDMA AllReduce (both
-phases) and All2All under ``shard_map`` on all four chips. Nothing runs.
+phases) and All2All under ``shard_map`` on all four chips; and the
+serving decode step at Qwen3-14B widths, whose weights are read by the
+``unflatten_cast`` kernel and whose embedding gathers only the tokens'
+rows, against a training step that takes neither. Nothing runs.
 
 The topology is described inside a module fixture, never at import:
 only one process may hold the TPU library at a time.
 """
 import collections
+import dataclasses
 import re
+import time
 
 import numpy as np
 import pytest
@@ -23,10 +28,19 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config, get_smoke_config
 from repro.core import codec
 from repro.core.comm_config import CommConfig
+from repro.core.policy import paper_policy
 from repro.kernels import ops, rdma_all2all, rdma_allreduce
 from repro.kernels.wire import decode_wire, encode_wire
+from repro.models.model import param_groups
+from repro.parallel import shardings
+from repro.parallel.plan import make_plan
+from repro.train.optim import OptimConfig
+from repro.train.serve_step import decode_cache_specs, make_decode_step
+from repro.train.train_step import (batch_spec, init_train_state,
+                                    make_train_step)
 
 # the benchmark plan's codec configs: paper TP int8, paper A2A int4, a
 # bit-split width, and int2 with spike reserving
@@ -142,3 +156,101 @@ def test_rdma_all2all_compiles(mesh4):
     x = jax.ShapeDtypeStruct((4, 4, 8, 5120), jnp.bfloat16,
                              sharding=NamedSharding(mesh4, P("model")))
     assert _kernels(jax.jit(f).lower(x).compile()) == {"rdma_all2all": 1}
+
+
+def _store(cfg, plan, mesh):
+    sharding = NamedSharding(mesh, shardings.store_spec(plan))
+    return {g: {n: jax.ShapeDtypeStruct((k, plan.tp, sp.flat_len(plan)),
+                                        jnp.float32, sharding=sharding)
+                for n, sp in specs.items()}
+            for g, (k, specs) in param_groups(cfg, plan).items()}
+
+
+def _kernel_scopes(hlo: str, name: str) -> collections.Counter:
+    """Calls of kernel ``name`` by the model-step scope they sit in."""
+    return collections.Counter(
+        ("layers" if "/layers/" in m else "head" if "/head/" in m else m)
+        for m in re.findall(rf'%{name}(?:\.\d+)? = [^\n]*?'
+                            r'custom_call_target="tpu_custom_call"[^\n]*?'
+                            r'op_name="([^"]*)"', hlo))
+
+
+def _unfused(hlo: str) -> str:
+    """The instructions of every computation but fusion bodies: what the
+    step writes to memory."""
+    fused = set(re.findall(r" fusion\([^\n]*?calls=%([\w.-]+)", hlo))
+    out, keep = [], False
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.-]+) [^\n]*\{$", line)
+        if head:
+            keep = head.group(1) not in fused
+        elif keep:
+            out.append(line)
+    return "\n".join(out)
+
+
+def test_decode_step_reads_weights_once(topo, monkeypatch):
+    """Qwen3-14B decode at published widths, 1 layer, batch 32, cache
+    1024: seven weight reads in the layer body and one for the head go
+    through ``unflatten_cast``; nothing writes the whole embedding table,
+    nor a padded bfloat16 copy of a matrix's store row."""
+    monkeypatch.setattr(shardings, "_backend", lambda: "tpu")
+    cfg = dataclasses.replace(get_config("qwen3-14b"), pattern_repeats=1)
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    plan = make_plan(cfg, tp=1, fsdp=1)
+    batch, cache_len = 32, 1024
+    shapes, specs = decode_cache_specs(cfg, plan, mesh, batch, cache_len)
+    caches = jax.tree.map(lambda c, p: jax.ShapeDtypeStruct(
+        c.shape, c.dtype, sharding=NamedSharding(mesh, p)), shapes, specs)
+    toks = jax.ShapeDtypeStruct(
+        (batch, 1), jnp.int32,
+        sharding=NamedSharding(mesh, batch_spec(batch, mesh)))
+    t0 = time.perf_counter()
+    step = make_decode_step(cfg, plan, paper_policy(), mesh, batch,
+                            cache_len)
+    hlo = step.lower(_store(cfg, plan, mesh), caches,
+                     {"tokens": toks}).compile().as_text()
+    assert time.perf_counter() - t0 < 60
+    assert _kernel_scopes(hlo, "unflatten_cast") == {"layers": 7, "head": 1}
+    table = rf"\[{plan.vocab_pad},{cfg.d_model}\]"
+    writers = re.findall(rf"%([\w.-]+) = \w+{table}", _unfused(hlo))
+    assert len(writers) == 1 and writers[0].startswith("unflatten_cast"), \
+        writers
+    # what is left cast from a padded store row: the 1-D gains
+    padded = [int(n) for n in re.findall(r"bf16\[\d+,1,(\d+)\]", hlo)]
+    assert padded and max(padded) <= cfg.d_model, padded
+
+
+def test_train_step_keeps_gather_path(topo, monkeypatch):
+    """Training differentiates through ``gather_param``: the kernel,
+    which has no VJP, is not in its step, though serving at the same
+    size (every tensor let through, smoke widths being under one block)
+    reads its weights through it."""
+    from repro.kernels import unflatten_cast
+    monkeypatch.setattr(shardings, "_backend", lambda: "tpu")
+    monkeypatch.setattr(unflatten_cast, "_MIN_VALUES", 0)
+    cfg = get_smoke_config("qwen3-14b")
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    plan = make_plan(cfg, tp=1, fsdp=1)
+    shapes, specs = decode_cache_specs(cfg, plan, mesh, 4, 64)
+    caches = jax.tree.map(lambda c, p: jax.ShapeDtypeStruct(
+        c.shape, c.dtype, sharding=NamedSharding(mesh, p)), shapes, specs)
+    toks = jax.ShapeDtypeStruct(
+        (4, 1), jnp.int32, sharding=NamedSharding(mesh, batch_spec(4, mesh)))
+    serve = make_decode_step(cfg, plan, paper_policy(), mesh, 4, 64).lower(
+        _store(cfg, plan, mesh), caches, {"tokens": toks}).compile()
+    assert _kernels(serve)["unflatten_cast"] > 0
+    opt_cfg = OptimConfig(lr=1e-3, warmup_steps=2, total_steps=20)
+    store = _store(cfg, plan, mesh)
+    rep = NamedSharding(mesh, P())
+    opt = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=NamedSharding(
+                mesh, shardings.STORE_SPEC) if a.ndim == 3 else rep),
+        jax.eval_shape(lambda s: init_train_state(s, opt_cfg), store))
+    bs = NamedSharding(mesh, batch_spec(4, mesh))
+    batch = {k: jax.ShapeDtypeStruct((4, 64), jnp.int32, sharding=bs)
+             for k in ("tokens", "labels")}
+    step = make_train_step(cfg, plan, paper_policy(), opt_cfg, mesh, 4)
+    hlo = step.lower(store, opt, batch).compile().as_text()
+    assert "unflatten_cast" not in hlo
